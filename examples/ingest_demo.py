@@ -86,6 +86,9 @@ def main() -> int:
         assert abs(ot - post) < 1e-2, (ot, post)
         print(f"oracle total {ot:.1f} matches — streamed ingest ≡ "
               f"one-shot construction")
+        st = client.stats()
+        assert st["server"].get("errors", 0.0) == 0.0, st["server"]
+        assert st["ingest"]["edges"]["compact_errors"] == 0, st["ingest"]
         print("OK")
         return 0
     finally:

@@ -61,6 +61,7 @@ def main() -> int:
             "repeated query re-planned"
 
         st = client.stats()["server"]
+        assert st.get("errors", 0.0) == 0.0, f"{st['errors']} error responses"
         print(f"server: {st['requests']:.0f} requests, "
               f"p50 {st['p50_s'] * 1e3:.1f} ms, "
               f"p99 {st['p99_s'] * 1e3:.1f} ms, "

@@ -170,11 +170,14 @@ def _scope_findings(scope: ast.AST, path: str) -> List[Finding]:
 def _kernel_triple_findings(tree: ast.Module, text: str,
                             path: str) -> List[Finding]:
     """D4M104: kernels/*/ops.py must dispatch ref AND interpret AND
-    pallas (string-literal impl names in the module)."""
+    pallas (string-literal impl names in the module; a call of a
+    ``*_pallas`` kernel counts as the pallas path)."""
     p = Path(path)
     if p.name != "ops.py" or "kernels" not in p.parts:
         return []
     impls = set(re.findall(r'"(ref|interpret|pallas)"', text))
+    if re.search(r"\w_pallas\(", text):
+        impls.add("pallas")
     missing = {"ref", "interpret", "pallas"} - impls
     if missing:
         return [Finding(

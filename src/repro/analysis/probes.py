@@ -46,7 +46,7 @@ def probe_for(name: str):
 @functools.lru_cache(maxsize=1)
 def _abstract_mesh():
     from jax.sharding import AbstractMesh
-    return AbstractMesh((("data", _NSHARDS),))
+    return AbstractMesh((_NSHARDS,), ("data",))
 
 
 def _sds(shape, dtype):

@@ -23,7 +23,7 @@ def make_dataset(n: int, seed: int = SEED):
     letters = np.array(list("abcdefghijklmnopqrstuvwxyz"))
     def strs():
         idx = rng.integers(0, 26, size=(m, 8))
-        return np.array(["".join(row) for row in letters[idx]])
+        return np.ascontiguousarray(letters[idx]).view("<U8").ravel()
     return {
         "rows": ints().astype(str),
         "rows2": ints().astype(str),

@@ -19,6 +19,8 @@ benchmarks and tests can assert a fast path actually fired:
 memoization), ``DISPATCH_STATS`` (selection execution paths) and
 ``PLAN_STATS`` (expression hash-consing + planner rewrites).
 """
+from repro.kernels import reset_kernel_stats
+
 from .assoc import Assoc
 from .assoc_tensor import AssocTensor, DISPATCH_STATS
 from .coo import (aggregate_runs, canonicalize_np, dedup_sorted_coo,
@@ -45,8 +47,9 @@ def reset_all_stats():
 
     Covers ``UNION_STATS`` (and drops the keyspace-union cache),
     ``CACHE_STATS`` (selector compilation — counters only; compiled
-    selectors stay warm), ``DISPATCH_STATS`` (selection execution paths)
-    and ``PLAN_STATS`` (and drops the plan cache).  Tests get this
+    selectors stay warm), ``DISPATCH_STATS`` (selection execution paths),
+    ``PLAN_STATS`` (and drops the plan cache) and the kernels'
+    ``KERNEL_STATS`` (impl resolutions per trace).  Tests get this
     between cases from the autouse fixture in ``tests/conftest.py``;
     benchmarks call it before a measured region.
     """
@@ -55,6 +58,7 @@ def reset_all_stats():
     for k in DISPATCH_STATS:
         DISPATCH_STATS[k] = 0
     reset_plan_stats()
+    reset_kernel_stats()
 
 
 __all__ = [

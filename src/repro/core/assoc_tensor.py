@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional, Tuple
 
 import jax
@@ -89,6 +89,14 @@ DISPATCH_STATS = {"range": 0, "multirange": 0, "hybrid": 0, "gather": 0}
 
 # Dict += is a read-modify-write: serve workers bump these concurrently.
 _DISPATCH_LOCK = threading.Lock()
+
+
+@lru_cache(maxsize=None)
+def _canonicalize(combine):
+    """The constructor's canonicalization as ONE compiled program per ⊕:
+    run eagerly, ``dedup_sorted_coo`` is ~100 separately compiled ops,
+    about a minute per n = 18 table on a TPU v5e."""
+    return jax.jit(partial(dedup_sorted_coo, combine=combine))
 
 
 def _bump_dispatch(key: str) -> None:
@@ -181,7 +189,7 @@ class AssocTensor:
         # zero-drop below only removes true sentinels, not rank 0.
         if val_space is not None:
             vj = jnp.where(rj != SENT, vj + 1.0, 0.0)
-        rows, cols, vals, nnz = dedup_sorted_coo(rj, cj, vj, agg)
+        rows, cols, vals, nnz = _canonicalize(agg)(rj, cj, vj)
         return AssocTensor(rows, cols, vals, nnz, row_space, col_space, val_space)
 
     @staticmethod

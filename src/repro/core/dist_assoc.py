@@ -57,7 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.analysis.contracts import contract
 
@@ -111,7 +111,7 @@ def _matmul_prog(mesh: Mesh, sr, expand: int, out_cap: int):
 
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(spec, P(), P(), P()),
-             out_specs=out_spec, check_rep=False)
+             out_specs=out_spec, check_vma=False)
     def go(a, br, bc, bv):
         pr, pc, pv, _ = expand_join_coo(
             a["rows"][0], a["cols"][0], a["vals"][0], br, bc, bv,
@@ -133,7 +133,7 @@ def _matmul_reduce_prog(mesh: Mesh, sr, expand: int, n_out: int, axis: int):
 
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(spec, P(), P(), P()),
-             out_specs=P(), check_rep=False)
+             out_specs=P(), check_vma=False)
     def go(a, br, bc, bv):
         pr, pc, pv, _ = expand_join_coo(
             a["rows"][0], a["cols"][0], a["vals"][0], br, bc, bv,
@@ -151,7 +151,7 @@ def _col_reduce_prog(mesh: Mesh, sr, nc: int, dt):
     @jax.jit
     @partial(shard_map, mesh=mesh,
              in_specs=(P("data"), P("data"), P("data")),
-             out_specs=P(), check_rep=False)
+             out_specs=P(), check_vma=False)
     def go(cols, vals, rows):
         ok = rows[0] != SENT
         vec = jnp.full((nc,), sr.zero, dt)
@@ -166,7 +166,7 @@ def _col_reduce_prog(mesh: Mesh, sr, nc: int, dt):
 def _col_degree_prog(mesh: Mesh, nc: int):
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(P("data"), P("data")),
-             out_specs=P(), check_rep=False)
+             out_specs=P(), check_vma=False)
     def go(cols, rows):
         ok = rows[0] != SENT
         vec = jnp.zeros((nc,), jnp.int32)
@@ -182,7 +182,7 @@ def _matvec_prog(mesh: Mesh, sr, nr: int, dt):
     @jax.jit
     @partial(shard_map, mesh=mesh,
              in_specs=(P("data"), P("data"), P("data"), P()),
-             out_specs=P(), check_rep=False)
+             out_specs=P(), check_vma=False)
     def go(rows, cols, vals, xv):
         ok = rows[0] != SENT
         contrib = sr.mul(jnp.where(ok, vals[0], sr.zero).astype(dt),
@@ -229,7 +229,7 @@ def _reduce_add_n_prog(mesh: Mesh, sr, axis: int, n_out: int, n_terms: int):
 
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(spec,) * n_terms,
-             out_specs=P(), check_rep=False)
+             out_specs=P(), check_vma=False)
     def go(*parts):
         vec = jnp.full((n_out,), sr.zero, jnp.float32)
         for p in parts:
@@ -255,7 +255,7 @@ def _select_prog(mesh: Mesh, row_gather: bool, col_gather: bool):
 
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(spec, P(), P(), P()),
-             out_specs=spec, check_rep=False)
+             out_specs=spec, check_vma=False)
     def go(a, bnds, rm, cm):
         a0 = jax.tree.map(lambda x: x[0], a)
         # same raw-array primitives as AssocTensor — layers cannot drift
@@ -279,7 +279,7 @@ def _setvals_prog(mesh: Mesh, row_gather: bool, col_gather: bool):
 
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(spec, P(), P(), P(), P()),
-             out_specs=P("data", None), check_rep=False)
+             out_specs=P("data", None), check_vma=False)
     def go(a, bnds, rm, cm, val):
         a0 = jax.tree.map(lambda x: x[0], a)
         keep = _shard_selection_keep(a0, row_gather, col_gather,
@@ -298,7 +298,7 @@ def _ewise_prog(mesh: Mesh, sr, op: str):
 
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(spec, spec), out_specs=spec,
-             check_rep=False)
+             check_vma=False)
     def go(a, b):
         # keyspaces are host metadata; inside shard_map the algebra runs
         # on raw rank arrays via the same canonicalization primitive the
@@ -374,7 +374,7 @@ def _matmul_a2a_prog(mesh: Mesh, sr, expand: int, bucket_cap: int,
     @jax.jit
     @partial(shard_map, mesh=mesh,
              in_specs=(P(), P(), P(), b_spec, P(), P()),
-             out_specs=out_spec, check_rep=False)
+             out_specs=out_spec, check_vma=False)
     def go(ar, ac, av, b, bm, bounds):
         # rerank the resident B block's rows onto the merged contraction
         # space in-program (bm is monotone, so the block stays sorted);
@@ -428,7 +428,7 @@ def _matmul_ring_prog(mesh: Mesh, sr, pr: int, pc: int, round_expand: int,
 
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(a_spec, a_spec),
-             out_specs=out_spec, check_rep=False)
+             out_specs=out_spec, check_vma=False)
     def go(a, b):
         ar, ac, av = a["rows"][0], a["cols"][0], a["vals"][0]
         bpk = _pack_coo(b["rows"][0], b["cols"][0], b["vals"][0])
@@ -468,7 +468,7 @@ def _matmul_reduce_a2a_prog(mesh: Mesh, sr, expand: int, n_out: int,
 
     @jax.jit
     @partial(shard_map, mesh=mesh, in_specs=(P(), P(), P(), b_spec, P()),
-             out_specs=P(), check_rep=False)
+             out_specs=P(), check_vma=False)
     def go(ar, ac, av, b, bm):
         rb0 = b["rows"][0]
         okb = rb0 != SENT
@@ -512,7 +512,7 @@ def _matmul_bsr_prog(mesh: Mesh, sr, n_a: int, n_c: int, m: int, n: int,
     @partial(shard_map, mesh=mesh,
              in_specs=(shard1, shard1, shard1, shard1, P(),
                        shard1, shard1, shard1, P("data", None, None)),
-             out_specs=out_spec, check_rep=False)
+             out_specs=out_spec, check_vma=False)
     def go(av, tof, lr, lc, b_tiles, pa, pb, pcc, cblk):
         from repro.kernels.bsr_spgemm.ops import bsr_pairlist
         a_tiles = jnp.full((n_a, TILE, TILE), sr.zero, jnp.float32)
@@ -628,9 +628,10 @@ class DistAssoc:
         """Gather all shards to a host Assoc (small-data paths/tests)."""
         from .assoc import Assoc
         n_shards = self.mesh.shape["data"]
+        host = jax.tree.map(np.asarray, self.local)   # one device→host copy
         merged = None
         for s in range(n_shards):
-            local = jax.tree.map(lambda x: x[s], self.local)
+            local = jax.tree.map(lambda x: x[s], host)
             a = local.to_assoc()
             merged = a if merged is None else merged + a if a.nnz() else merged
         return merged
@@ -645,9 +646,10 @@ class DistAssoc:
         (legitimate under min/max-family semirings whose ⊕-identity is
         ±inf) must survive chained products.
         """
-        rows = self.local.rows.reshape(-1)
-        cols = self.local.cols.reshape(-1)
-        vals = self.local.vals.reshape(-1)
+        repl = NamedSharding(self.mesh, P())
+        rows, cols, vals = (jax.device_put(x.reshape(-1), repl)
+                            for x in (self.local.rows, self.local.cols,
+                                      self.local.vals))
         r, c, v, nnz = coo_compact(rows, cols, vals, rows != SENT)
         return AssocTensor(r, c, v, nnz, self.local.row_space,
                            self.local.col_space, self.local.val_space)
@@ -876,8 +878,11 @@ class DistAssoc:
         # device: rerank the sharded A cols onto the contraction space
         ok = a_loc.rows != SENT
         cm = jnp.asarray(a_map) if len(a_map) else jnp.zeros(1, jnp.int32)
-        a_cols = jnp.where(ok, cm[jnp.clip(a_loc.cols, 0, cm.shape[0] - 1)],
-                           SENT)
+        # the gather keeps A's row sharding: a mesh with Explicit axes
+        # (jax.make_mesh's default) cannot infer it from a replicated table
+        a_cols = jnp.where(
+            ok, cm.at[jnp.clip(a_loc.cols, 0, cm.shape[0] - 1)].get(
+                out_sharding=a_loc.cols.sharding), SENT)
         a_rows_h = np.asarray(a_loc.rows)
         a_cols_h = np.asarray(a_cols)
 

@@ -1058,7 +1058,7 @@ def _dist_add_n(terms, sr):
     from functools import partial
 
     import jax
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from .assoc_tensor import AssocTensor
@@ -1071,7 +1071,7 @@ def _dist_add_n(terms, sr):
             "vals": P("data", None), "nnz": P("data")}
 
     @partial(shard_map, mesh=d0.mesh, in_specs=(spec,) * len(dicts),
-             out_specs=spec, check_rep=False)
+             out_specs=spec, check_vma=False)
     def go(*parts):
         rows = jnp.concatenate([p["rows"][0] for p in parts])
         cols = jnp.concatenate([p["cols"][0] for p in parts])

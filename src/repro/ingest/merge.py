@@ -34,7 +34,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.analysis.contracts import contract
 from repro.core.assoc_tensor import coo_compact
@@ -138,7 +138,7 @@ def _dist_merge_prog(mesh, aggregate: str, rerank: bool):
     @jax.jit
     @partial(shard_map, mesh=mesh,
              in_specs=(spec, dspec, dspec, dspec, P(), P()),
-             out_specs=spec, check_rep=False)
+             out_specs=spec, check_vma=False)
     def go(a, dr, dc, dv, rmap, cmap):
         a0 = jax.tree.map(lambda x: x[0], a)
         br, bc, bv = a0["rows"], a0["cols"], a0["vals"]

@@ -31,6 +31,7 @@ layer's own semantics.
 """
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -38,6 +39,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 __all__ = ["IngestTable", "Compactor"]
+
+_log = logging.getLogger(__name__)
 
 
 def _next_pow2(n: int) -> int:
@@ -98,7 +101,7 @@ class IngestTable:
         self._retired: List[Any] = []   # superseded arrays, pending invalidation
         self.stats: Dict[str, int] = {
             "inserts": 0, "insert_triples": 0, "reads": 0, "merges": 0,
-            "compactions": 0,
+            "compactions": 0, "compact_errors": 0,
         }
         if self.layer == "dist":
             self._nshards = base.mesh.shape["data"]
@@ -338,6 +341,11 @@ class IngestTable:
             return True
         return False
 
+    def note_compact_error(self) -> None:
+        """Count a failed background compaction (reported by ``info()``)."""
+        with self._lock:
+            self.stats["compact_errors"] += 1
+
     # -- telemetry -----------------------------------------------------------
     def info(self) -> Dict[str, Any]:
         with self._lock:
@@ -384,10 +392,19 @@ class Compactor:
             self._thread = None
 
     def _loop(self) -> None:
+        from repro.serve.wire import WireError
+
         while not self._stop.wait(self.interval_s):
             for name in self.registry.ingest_names():
                 try:
-                    self.registry.ingest_table(name).maybe_compact(
-                        idle_s=self.idle_s)
-                except Exception:      # table dropped mid-iteration etc.
-                    continue
+                    table = self.registry.ingest_table(name)
+                except WireError as exc:
+                    if exc.code == "unknown_table":   # dropped mid-iteration
+                        continue
+                    raise
+                try:
+                    table.maybe_compact(idle_s=self.idle_s)
+                except Exception:   # the loop outlives one bad table
+                    table.note_compact_error()
+                    _log.exception("background compaction of table %r "
+                                   "failed", name)

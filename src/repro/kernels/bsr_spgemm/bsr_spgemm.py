@@ -21,8 +21,8 @@ Two entry points:
   server-side-combine pushdown for ``sqin``/``sqout``/degree queries.
 
 Accumulation is semiring-generic for every registered algebra: ``(+,×)``
-contracts on the MXU, everything else on the VPU via 32-wide k-slabs (a
-[bm, 32, bn] f32 broadcast is 2 MiB of VMEM).  Skipped tiles still stream
+contracts on the MXU, everything else on the VPU as one rank-1 update per
+k (:func:`_tile_product`).  Skipped tiles still stream
 through VMEM (BlockSpec prefetch is unconditional) — the win is MXU/VPU
 time, and HBM→VMEM for A could be further elided with a scalar-prefetch
 index map (left as a §Perf note).
@@ -43,11 +43,13 @@ def _tile_product(a, b, *, sr: Semiring):
     """One-tile semiring contraction ``[bm, bk] ⊗.⊕ [bk, bn] → [bm, bn]``."""
     if sr.mxu:
         return jnp.dot(a, b, preferred_element_type=jnp.float32)
-    # VPU path: sub-slab the K tile so the broadcast product stays in VMEM
-    part = jnp.full((a.shape[0], b.shape[1]), sr.zero, jnp.float32)
-    for k0 in range(0, a.shape[1], 32):
-        prod = sr.mul(a[:, k0:k0 + 32, None], b[None, k0:k0 + 32, :])
-        part = sr.add(part, sr.add_reduce(prod, axis=1))
+    # VPU path: one rank-1 ⊗-update per k, unrolled over the tile — A's
+    # column k is lane-broadcast and B's row k sublane-broadcast.  A
+    # [bm, bk, bn] broadcast would need a lane→sublane relayout, which the
+    # TPU compiler rejects.
+    part = sr.mul(a[:, 0:1], b[0:1, :])
+    for k in range(1, a.shape[1]):
+        part = sr.add(part, sr.mul(a[:, k:k + 1], b[k:k + 1, :]))
     return part
 
 

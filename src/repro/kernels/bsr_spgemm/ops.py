@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.semiring import get_semiring
+from repro.kernels import resolve_impl
 from .bsr_spgemm import bsr_spgemm_pallas, bsr_spgemm_reduce_pallas
 from .pairlist import bsr_pairlist_pallas, bsr_pairlist_reduce_pallas
 from .ref import (bsr_pairlist_ref, bsr_pairlist_reduce_ref, bsr_spgemm_ref,
@@ -25,8 +26,7 @@ def make_block_mask(rows, cols, valid, mb: int, kb: int, *, bm=128, bk=128):
 def bsr_spgemm(a, block_mask, b, *, semiring="plus_times", impl="auto",
                bm: int = 128, bn: int = 128, bk: int | None = None):
     sr = get_semiring(semiring)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl("bsr_spgemm", impl)
     if impl == "ref":
         return bsr_spgemm_ref(a, block_mask, b, semiring=sr, bm=bm, bk=bk)
     return bsr_spgemm_pallas(a, block_mask, b, semiring=sr, bm=bm, bn=bn,
@@ -46,8 +46,7 @@ def bsr_spgemm_reduce(a, block_mask, b, *, axis: int,
     unfused oracle (materialize-then-reduce) used on non-TPU backends.
     """
     sr = get_semiring(semiring)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl("bsr_spgemm_reduce", impl)
     if impl == "ref":
         return bsr_spgemm_reduce_ref(a, block_mask, b, axis=axis,
                                      semiring=sr, bm=bm, bk=bk)
@@ -63,19 +62,49 @@ def bsr_spgemm_reduce(a, block_mask, b, *, axis: int,
 # invariant; the kernel's VMEM-resident output accumulation depends on it.
 # ---------------------------------------------------------------------------
 
+# pairs per kernel call: three int32 lists of this length take 192 KiB of
+# the 1 MiB SMEM a v5e core has for scalar-prefetch operands
+MAX_PAIRS = 16384
+
+
+def _chunked_pairlist(kernel, acc, pair_a, pair_b, pair_o):
+    """Feed the sorted pair list through ``kernel`` in SMEM-sized chunks.
+
+    ``acc`` holds one extra ⊕-identity slot: pad pairs point at it (they
+    sort after every real slot) and it is dropped on return.  A run of
+    pairs split across two chunks resumes from the partial the first
+    chunk wrote (the kernels start each run from the accumulator's value).
+    """
+    n_pairs = pair_a.shape[0]
+    chunk = min(n_pairs, MAX_PAIRS)
+    n_chunks = -(-n_pairs // chunk)
+    pad = n_chunks * chunk - n_pairs
+    pa = jnp.pad(pair_a, (0, pad))
+    pb = jnp.pad(pair_b, (0, pad))
+    po = jnp.pad(pair_o, (0, pad), constant_values=acc.shape[0] - 1)
+
+    def step(i, acc):
+        take = lambda x: jax.lax.dynamic_slice_in_dim(x, i * chunk, chunk)
+        return kernel(take(pa), take(pb), take(po), acc)
+
+    return jax.lax.fori_loop(0, n_chunks, step, acc)[:-1]
+
+
 @partial(jax.jit, static_argnames=("n_c", "semiring", "impl"))
 def bsr_pairlist(a_tiles, b_tiles, pair_a, pair_b, pair_c, *, n_c: int,
                  semiring="plus_times", impl="auto"):
     """Pair-list BSR contraction → packed C tiles ``[n_c, bm, bn]``."""
     sr = get_semiring(semiring)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl("bsr_pairlist", impl)
     if impl == "ref":
         return bsr_pairlist_ref(a_tiles, b_tiles, pair_a, pair_b, pair_c,
                                 n_c=n_c, semiring=sr)
-    return bsr_pairlist_pallas(a_tiles, b_tiles, pair_a, pair_b, pair_c,
-                               n_c=n_c, semiring=sr,
-                               interpret=(impl == "interpret"))
+    bm, bn = a_tiles.shape[1], b_tiles.shape[2]
+    c_tiles = jnp.full((n_c + 1, bm, bn), sr.zero, jnp.float32)
+    return _chunked_pairlist(
+        partial(bsr_pairlist_pallas, a_tiles, b_tiles, semiring=sr,
+                interpret=(impl == "interpret")),
+        c_tiles, pair_a, pair_b, pair_c)
 
 
 @partial(jax.jit, static_argnames=("n_o", "axis", "semiring", "impl"))
@@ -90,14 +119,16 @@ def bsr_pairlist_reduce(a_tiles, b_tiles, pair_a, pair_b, pair_o, *,
     residual 128 lanes / 8 sublanes.
     """
     sr = get_semiring(semiring)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl("bsr_pairlist_reduce", impl)
     if impl == "ref":
         return bsr_pairlist_reduce_ref(a_tiles, b_tiles, pair_a, pair_b,
                                        pair_o, n_o=n_o, axis=axis,
                                        semiring=sr)
-    part = bsr_pairlist_reduce_pallas(a_tiles, b_tiles, pair_a, pair_b,
-                                      pair_o, n_o=n_o, axis=axis,
-                                      semiring=sr,
-                                      interpret=(impl == "interpret"))
+    acc_shape = ((a_tiles.shape[1], 128) if axis == 1
+                 else (8, b_tiles.shape[2]))
+    partials = jnp.full((n_o + 1,) + acc_shape, sr.zero, jnp.float32)
+    part = _chunked_pairlist(
+        partial(bsr_pairlist_reduce_pallas, a_tiles, b_tiles, axis=axis,
+                semiring=sr, interpret=(impl == "interpret")),
+        partials, pair_a, pair_b, pair_o)
     return sr.add_reduce(part, axis=2 if axis == 1 else 1)

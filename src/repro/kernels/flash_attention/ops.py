@@ -14,6 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_impl
 from .flash_attention import flash_attention_pallas
 from .ref import flash_attention_ref
 
@@ -30,8 +31,7 @@ def flash_attention(q, k, v, *, q_positions=None, k_positions=None,
     parity with the reference path).  Decode over ring caches
     (``k_valid_len``) routes to the reference implementation.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl("flash_attention", impl)
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)

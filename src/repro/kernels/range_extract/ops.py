@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sorted_ops import INT_SENTINEL
+from repro.kernels import resolve_impl
 from .ref import range_mask_ref
 from .range_extract import range_mask_pallas
 
@@ -26,8 +27,7 @@ def range_mask(rows: jnp.ndarray, cols: jnp.ndarray, bounds: jnp.ndarray,
     ``rows``/``cols``: int32[N] sentinel-padded; ``bounds``: int32 array
     of 4 entries (row_lo, row_hi, col_lo, col_hi), any shape.
     """
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl("range_mask", impl)
     bounds = bounds.reshape(1, 4).astype(jnp.int32)
     if impl == "ref":
         return range_mask_ref(rows, cols, bounds)

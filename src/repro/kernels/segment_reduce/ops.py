@@ -6,6 +6,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_impl
 from .ref import segment_scan_ref
 from .segment_reduce import segment_scan_pallas
 
@@ -13,8 +14,7 @@ from .segment_reduce import segment_scan_pallas
 @partial(jax.jit, static_argnames=("combine", "impl"))
 def segment_scan(keys, vals, *, combine: str = "sum", impl: str = "auto"):
     """Inclusive segmented ⊕-scan; run-last positions hold run totals."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl("segment_scan", impl)
     if impl == "ref":
         return segment_scan_ref(keys, vals, combine=combine)
     n = keys.shape[0]

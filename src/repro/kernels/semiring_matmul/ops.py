@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.semiring import get_semiring
+from repro.kernels import resolve_impl
 from .ref import semiring_matmul_ref
 from .semiring_matmul import semiring_matmul_pallas
 
@@ -27,7 +28,7 @@ def _pad_to(x, mult_r, mult_c, fill):
 @partial(jax.jit, static_argnames=("semiring", "impl", "bm", "bn", "bk"))
 def semiring_matmul(a: jnp.ndarray, b: jnp.ndarray, *, semiring="plus_times",
                     impl: str = "auto", bm: int = 128, bn: int = 128,
-                    bk: int | None = None) -> jnp.ndarray:
+                    bk: int = 128) -> jnp.ndarray:
     """Semiring contraction with shape-padding; returns [M, N] fp32.
 
     impl: "pallas" (TPU), "interpret" (kernel body on CPU), "ref" (jnp),
@@ -35,13 +36,11 @@ def semiring_matmul(a: jnp.ndarray, b: jnp.ndarray, *, semiring="plus_times",
     """
     sr = get_semiring(semiring)
     m, n = a.shape[0], b.shape[1]
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl("semiring_matmul", impl)
     if impl == "ref":
         return semiring_matmul_ref(a, b, semiring=sr)
-    kb = bk or (128 if sr.mxu else 32)
-    ap = _pad_to(a.astype(jnp.float32), bm, kb, sr.zero)
-    bp = _pad_to(b.astype(jnp.float32), kb, bn, sr.zero)
-    out = semiring_matmul_pallas(ap, bp, semiring=sr, bm=bm, bn=bn, bk=kb,
+    ap = _pad_to(a.astype(jnp.float32), bm, bk, sr.zero)
+    bp = _pad_to(b.astype(jnp.float32), bk, bn, sr.zero)
+    out = semiring_matmul_pallas(ap, bp, semiring=sr, bm=bm, bn=bn, bk=bk,
                                  interpret=(impl == "interpret"))
     return out[:m, :n]

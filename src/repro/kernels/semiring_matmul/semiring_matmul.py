@@ -7,9 +7,10 @@ MXU-aligned tiles (see DESIGN.md §2) and contract with the semiring:
   * ``(+,×)``  — ``jnp.dot`` on the 128×128 MXU, fp32 accumulation;
   * ``(max,+) / (min,+) / (max,min) / (max,×)`` — no MXU analogue exists
     (the systolic array hard-wires multiply-accumulate), so the contraction
-    runs on the VPU as a broadcast ⊗ over a k-slab followed by an ⊕-reduce.
-    k-slabs are kept small (``bk=32``) so the [bm, bk, bn] broadcast stays
-    within VMEM.
+    runs on the VPU as one rank-1 ⊗-update per k, ⊕-folded into the tile
+    (the BSR kernels' shared ``_tile_product``).
+
+Every block is 128×128, on the TPU's 8×128 tiling for every semiring.
 
 Grid is (M/bm, N/bn, K/bk) with the K dimension innermost/sequential; a
 VMEM scratch accumulator carries partial ⊕ results across K steps and is
@@ -25,6 +26,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.semiring import Semiring, get_semiring
+from repro.kernels.bsr_spgemm.bsr_spgemm import _tile_product
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, sr: Semiring, nk: int):
@@ -34,16 +36,8 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, sr: Semiring, nk: int):
     def _init():
         acc_ref[...] = jnp.full_like(acc_ref, sr.zero)
 
-    a = a_ref[...]
-    b = b_ref[...]
-    if sr.mxu:
-        part = jnp.dot(a, b, preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] + part
-    else:
-        # VPU path: ⊗ broadcast over the k slab, ⊕ reduce, ⊕ into acc
-        prod = sr.mul(a[:, :, None], b[None, :, :])      # [bm, bk, bn]
-        part = sr.add_reduce(prod, axis=1)
-        acc_ref[...] = sr.add(acc_ref[...], part)
+    part = _tile_product(a_ref[...], b_ref[...], sr=sr)
+    acc_ref[...] = sr.add(acc_ref[...], part)
 
     @pl.when(k == nk - 1)
     def _flush():
@@ -52,16 +46,13 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, sr: Semiring, nk: int):
 
 def semiring_matmul_pallas(a: jnp.ndarray, b: jnp.ndarray, *,
                            semiring="plus_times",
-                           bm: int = 128, bn: int = 128,
-                           bk: int | None = None,
+                           bm: int = 128, bn: int = 128, bk: int = 128,
                            interpret: bool = False) -> jnp.ndarray:
     """C[i,j] = ⊕_k A[i,k] ⊗ B[k,j].  A: [M,K], B: [K,N] (padded multiples)."""
     sr = get_semiring(semiring)
     m, kdim = a.shape
     k2, n = b.shape
     assert kdim == k2, (a.shape, b.shape)
-    if bk is None:
-        bk = 128 if sr.mxu else 32
     assert m % bm == 0 and n % bn == 0 and kdim % bk == 0, \
         (m, n, kdim, bm, bn, bk)
     nk = kdim // bk

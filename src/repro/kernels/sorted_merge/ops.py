@@ -13,27 +13,31 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.sorted_ops import INT_SENTINEL
+from repro.kernels import resolve_impl
 from .ref import rank_count_ref
 from .sorted_merge import rank_count_pallas
 
 
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
 @partial(jax.jit, static_argnames=("impl",))
 def rank_count(i, j, *, impl: str = "auto"):
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = resolve_impl("rank_count", impl)
     if impl == "ref":
         return rank_count_ref(i, j)
-    pad_i = (-i.shape[0]) % 512 if i.shape[0] > 512 else (-i.shape[0]) % 8
-    pad_j = (-j.shape[0]) % 512 if j.shape[0] > 512 else (-j.shape[0]) % 8
-    ip = jnp.pad(i, (0, pad_i), constant_values=INT_SENTINEL)
-    jp = jnp.pad(j, (0, pad_j), constant_values=INT_SENTINEL)
-    bi = min(512, ip.shape[0])
-    bj = min(512, jp.shape[0])
-    rank, hit = rank_count_pallas(ip, jp, bi=bi, bj=bj,
+    ni, nj = i.shape[0], j.shape[0]
+    rows = min(64, _round_up(-(-ni // 128), 8))
+    ip = jnp.pad(i, (0, _round_up(ni, rows * 128) - ni),
+                 constant_values=INT_SENTINEL).reshape(-1, 128)
+    bj = min(1024, _round_up(nj, 8))
+    jp = jnp.pad(j, (0, _round_up(nj, bj) - nj), constant_values=INT_SENTINEL)
+    rank, hit = rank_count_pallas(ip, jp, rows=rows, bj=bj,
                                   interpret=(impl == "interpret"))
     # sentinel tails in J inflate nothing (< any valid key is False), but
     # sentinel I entries count all valid J — callers mask by validity.
-    return rank[:i.shape[0]], hit[:i.shape[0]]
+    return rank.reshape(-1)[:ni], hit.reshape(-1)[:ni]
 
 
 @partial(jax.jit, static_argnames=("impl",))

@@ -92,8 +92,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         rec["extra"] = ((extra + ";") if extra else "") + overrides
 
     t0 = time.time()
-    if hasattr(jax, "set_mesh"):  # newer jax; 0.4.x relies on `with mesh:`
-        jax.set_mesh(mesh)
+    jax.set_mesh(mesh)
     with mesh:
         jitted, args = S.build_jitted(cfg, shape, mesh, opts)
         lowered = jitted.lower(*args)

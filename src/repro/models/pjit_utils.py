@@ -18,10 +18,7 @@ from jax.sharding import PartitionSpec as P
 
 
 def _current_axis_names() -> Tuple[str, ...]:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:  # pragma: no cover - very old jax
-        return ()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or not getattr(mesh, "axis_names", ()):
         return ()
     return tuple(mesh.axis_names)

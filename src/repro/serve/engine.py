@@ -384,6 +384,7 @@ class Engine:
         """The /stats body: server counters + core telemetry dicts."""
         from repro.core import (CACHE_STATS, DISPATCH_STATS, PLAN_STATS,
                                 UNION_STATS)
+        from repro.kernels import KERNEL_STATS
 
         merged = self.metrics()
         server: Dict[str, float] = {}
@@ -408,6 +409,7 @@ class Engine:
             "cache": dict(CACHE_STATS),
             "union": dict(UNION_STATS),
             "dispatch": dict(DISPATCH_STATS),
+            "kernels": dict(KERNEL_STATS),
             "queue_depth": len(self._queue),
             "workers": self.workers,
         }

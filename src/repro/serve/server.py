@@ -31,17 +31,37 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from pathlib import Path
 from typing import Optional
 
 from .engine import Engine, QueryError
 from .registry import TableRegistry
 from .wire import WireError
 
-__all__ = ["D4MServer", "start_server", "main"]
+__all__ = ["D4MServer", "start_server", "main", "use_compile_cache"]
 
 _MAX_BODY = 64 * 1024 * 1024
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as JAX reads it —
+    nothing is set in code.  Otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache`` (git-ignored), so every process started
+    from this checkout finds what an earlier one compiled.  Call it at
+    start-up, before the first compile."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -206,6 +226,7 @@ def main(argv=None) -> int:
     ap.add_argument("--batch-window-ms", type=float, default=0.0)
     args = ap.parse_args(argv)
 
+    use_compile_cache()
     registry = TableRegistry.from_specs(_load_specs(args.tables))
     server = D4MServer(registry, args.host, args.port,
                        workers=args.workers, max_batch=args.max_batch,

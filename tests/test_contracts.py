@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.analysis import (CONTRACT_REGISTRY, Contract, analyze_program,
@@ -25,7 +25,7 @@ from repro.analysis.hlo_contracts import parse_hlo
 
 
 def _mesh():
-    return AbstractMesh((("data", 8),))
+    return AbstractMesh((8,), ("data",))
 
 
 def _sds(shape, dtype=jnp.float32):
@@ -82,7 +82,7 @@ def _kinds(violations):
 
 def test_injected_psum_is_caught():
     f = shard_map(lambda x: jax.lax.psum(x, "data"), mesh=_mesh(),
-                  in_specs=P("data"), out_specs=P(), check_rep=False)
+                  in_specs=P("data"), out_specs=P(), check_vma=False)
     rep = analyze_program(lower_hlo(f, _sds((8, 16))))
     assert rep.collectives_total == 1
     viol = Contract(name="canary", collectives=0).check(rep)
@@ -98,7 +98,7 @@ def test_while_of_psums_counts_trip_weighted():
         out, _ = jax.lax.scan(step, x, None, length=5)
         return out
     f = shard_map(body, mesh=_mesh(), in_specs=P("data"), out_specs=P("data"),
-                  check_rep=False)
+                  check_vma=False)
     rep = analyze_program(lower_hlo(f, _sds((8, 16))))
     # a while of N psums is N collectives, not 1 — the walker multiplies
     # by the loop trip count
@@ -136,7 +136,7 @@ def test_partitioner_custom_calls_are_not_host_transfers():
     # Sharding/SPMDFullToShardShape markers in shard_map lowerings must
     # not count as host round-trips
     f = shard_map(lambda x: x * 2, mesh=_mesh(), in_specs=P("data"),
-                  out_specs=P("data"), check_rep=False)
+                  out_specs=P("data"), check_vma=False)
     rep = analyze_program(lower_hlo(f, _sds((8, 16))))
     assert rep.host_transfers == 0
     assert rep.collectives_total == 0

@@ -375,6 +375,34 @@ def test_background_compactor_idle_trigger():
         comp.stop()
 
 
+def test_background_compactor_counts_failures(monkeypatch, caplog):
+    """A failing compaction is counted in info() and logged, and the loop
+    keeps serving the other tables; it is never dropped silently."""
+    reg = TableRegistry()
+    bad = reg.register("bad", IngestTable(
+        AssocTensor.from_triples(*_BASE, aggregate="sum")))
+    good = reg.register("good", IngestTable(
+        AssocTensor.from_triples(*_BASE, aggregate="sum")))
+
+    def boom(idle_s):
+        raise RuntimeError("merge program failed")
+
+    monkeypatch.setattr(bad, "maybe_compact", boom)
+    comp = Compactor(reg, interval_s=0.02, idle_s=0.05).start()
+    try:
+        good.insert(["a"], ["b"], [1.0])
+        deadline = time.time() + 10
+        while time.time() < deadline:
+            if good.version == 1 and bad.info()["compact_errors"] >= 2:
+                break
+            time.sleep(0.02)
+        assert bad.info()["compact_errors"] >= 2
+        assert "merge program failed" in caplog.text
+        assert good.version == 1 and good.info()["compact_errors"] == 0
+    finally:
+        comp.stop()
+
+
 # ---------------------------------------------------------------------------
 # satellite riders: union-cache eviction counter, compare.py bootstrap
 # ---------------------------------------------------------------------------

@@ -19,7 +19,7 @@ from repro.kernels.semiring_matmul.ref import semiring_matmul_ref
 @pytest.mark.parametrize("sr", ["plus_times", "max_plus", "min_plus",
                                 "max_min", "max_times"])
 @pytest.mark.parametrize("shape", [(32, 48, 16), (128, 128, 128),
-                                   (70, 90, 130)])
+                                   (70, 90, 130), (200, 300, 140)])
 def test_semiring_matmul(sr, shape):
     m, k, n = shape
     a = jnp.asarray(rng.normal(size=(m, k)).astype(np.float32))
@@ -78,7 +78,8 @@ from repro.kernels.sorted_merge.ops import merge_positions, rank_count
 from repro.kernels.sorted_merge.ref import rank_count_ref
 
 
-@pytest.mark.parametrize("ni,nj", [(64, 64), (300, 500), (8, 1024)])
+@pytest.mark.parametrize("ni,nj", [(64, 64), (300, 500), (8, 1024),
+                                   (9000, 2100)])
 def test_rank_count(ni, nj):
     i = jnp.asarray(np.unique(rng.integers(0, 10000, ni)).astype(np.int32))
     j = jnp.asarray(np.unique(rng.integers(0, 10000, nj)).astype(np.int32))
@@ -158,7 +159,8 @@ from repro.core.semiring import REGISTRY as _SR_REGISTRY
 # semiring-generic accumulation: the block-skip kernel must match the jnp
 # oracle for EVERY registered algebra, not just the MXU-friendly ones
 @pytest.mark.parametrize("sr", sorted(_SR_REGISTRY))
-@pytest.mark.parametrize("mb,kb,n", [(2, 2, 128), (4, 3, 256)])
+@pytest.mark.parametrize("mb,kb,n", [(2, 2, 128), (4, 3, 256),
+                                     (1, 5, 128)])
 def test_bsr_spgemm(sr, mb, kb, n):
     a = jnp.asarray(rng.normal(size=(mb * 128, kb * 128)).astype(np.float32))
     mask = jnp.asarray((rng.random((mb, kb)) > 0.5).astype(np.int32))

@@ -1,6 +1,8 @@
 """d4mlint — the host-side AST anti-pattern rules (D4M101…D4M104)."""
 import textwrap
 
+import pytest
+
 from repro.analysis.lint import lint_file, lint_paths
 
 
@@ -35,17 +37,18 @@ def test_numpy_at_module_scope_is_fine():
     assert f == []
 
 
-def test_host_roundtrip_in_shard_map_body_is_d4m102():
+@pytest.mark.parametrize("call", ["shard_map", "jax.shard_map"])
+def test_host_roundtrip_in_shard_map_body_is_d4m102(call):
     # body passed BY NAME to shard_map — no decorator in sight
-    f = _lint("""
+    f = _lint(f"""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def body(x):
             x.block_until_ready()
             return x
 
-        go = shard_map(body, mesh=None, in_specs=None, out_specs=None)
+        go = {call}(body, mesh=None, in_specs=None, out_specs=None)
     """)
     assert _rules(f) == ["D4M102"]
 

@@ -14,8 +14,10 @@ import subprocess
 import sys
 import textwrap
 import warnings
+from functools import partial
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -74,6 +76,39 @@ def test_pairlist_empty_pair_list():
     for kernel_impl in ("ref", "interpret", "chunked"):
         out = da.matmul(db, impl="bsr", kernel_impl=kernel_impl).to_assoc()
         assert out.to_dict() == {}
+
+
+@pytest.mark.parametrize("axis", [None, 0, 1])
+@pytest.mark.parametrize("sr_name", ["plus_times", "min_plus"])
+def test_pairlist_chunks_split_runs(sr_name, axis, monkeypatch):
+    """A pair list longer than one SMEM chunk: runs straddling a chunk
+    boundary resume from the partial the earlier chunk wrote, and the
+    padded tail is dropped — equal to the jnp oracle."""
+    from repro.kernels.bsr_spgemm import ops
+    from repro.kernels.bsr_spgemm.ops import bsr_pairlist, bsr_pairlist_reduce
+    r = np.random.default_rng(9)
+    a = jnp.asarray(r.uniform(0.5, 2.0, (3, 128, 128)), jnp.float32)
+    b = jnp.asarray(r.uniform(0.5, 2.0, (4, 128, 128)), jnp.float32)
+    n_out = 4
+    po = np.sort(np.concatenate([np.arange(n_out),
+                                 r.integers(0, n_out, 7)])).astype(np.int32)
+    pa = jnp.asarray(r.integers(0, 3, po.size), jnp.int32)
+    pb = jnp.asarray(r.integers(0, 4, po.size), jnp.int32)
+    po = jnp.asarray(po)
+    if axis is None:
+        call = partial(bsr_pairlist, a, b, pa, pb, po, n_c=n_out,
+                       semiring=sr_name)
+    else:
+        call = partial(bsr_pairlist_reduce, a, b, pa, pb, po, n_o=n_out,
+                       axis=axis, semiring=sr_name)
+    want = np.asarray(call(impl="ref"))
+    monkeypatch.setattr(ops, "MAX_PAIRS", 3)      # 11 pairs → 4 chunks
+    for fn in (bsr_pairlist, bsr_pairlist_reduce):
+        fn.clear_cache()                          # retrace with the patch
+    got = np.asarray(call(impl="interpret"))
+    for fn in (bsr_pairlist, bsr_pairlist_reduce):
+        fn.clear_cache()
+    np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
 @pytest.mark.parametrize("axis", [0, 1])

@@ -3,6 +3,7 @@ hits across requests), HTTP server/client end-to-end, and the
 multithreaded hammer over the now-locked core caches."""
 import json
 import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -162,7 +163,7 @@ def test_engine_batches_compatible_requests(registry):
 def test_engine_stats_shape_and_reset(engine):
     engine.query(_pipeline_payload())
     st = engine.stats()
-    assert {"server", "plan", "cache", "union", "dispatch",
+    assert {"server", "plan", "cache", "union", "dispatch", "kernels",
             "queue_depth", "workers"} <= set(st)
     assert st["server"]["requests"] >= 1
     assert "p50_s" in st["server"] and "p99_s" in st["server"]
@@ -170,6 +171,23 @@ def test_engine_stats_shape_and_reset(engine):
     st2 = engine.stats()
     assert st2["server"].get("requests", 0.0) == 0.0
     assert st2["plan"]["plan_hits"] == 0
+
+
+def test_compile_cache_placement(monkeypatch):
+    import jax
+    from repro.serve.server import use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before  # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(Path(__file__).resolve().parent.parent / ".jax_cache")
+        assert use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 # ---------------------------------------------------------------------------

@@ -6,17 +6,8 @@ from jax.sharding import AbstractMesh, PartitionSpec as P
 from repro.configs import get_config
 from repro.launch import sharding as shd
 
-def _amesh(sizes, names):
-    """AbstractMesh across jax versions: new API takes (sizes, names),
-    jax<=0.4.x takes a tuple of (name, size) pairs."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH1 = _amesh((16, 16), ("data", "model"))
-MESH2 = _amesh((2, 16, 16), ("pod", "data", "model"))
+MESH1 = AbstractMesh((16, 16), ("data", "model"))
+MESH2 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class _Shape:
@@ -76,10 +67,10 @@ def test_no_double_axis_use():
 
 
 def test_batch_spec_degradation():
-    assert shd.batch_spec(256, _amesh((16, 16), ("data", "model"))) \
+    assert shd.batch_spec(256, AbstractMesh((16, 16), ("data", "model"))) \
         == P(("data",), None)
     # batch=1 cannot shard → replicated
-    assert shd.batch_spec(1, _amesh((16, 16), ("data", "model"))) \
+    assert shd.batch_spec(1, AbstractMesh((16, 16), ("data", "model"))) \
         == P(None, None)
     assert shd.batch_spec(256, MESH2) == P(("pod", "data"), None)
 
