@@ -16,13 +16,14 @@
 Telemetry counters (and their reset helpers) are exported together so
 benchmarks and tests can assert a fast path actually fired:
 ``CACHE_STATS`` (selector compilation), ``UNION_STATS`` (keyspace-union
-memoization), ``DISPATCH_STATS`` (selection execution paths) and
-``PLAN_STATS`` (expression hash-consing + planner rewrites).
+memoization), ``DISPATCH_STATS`` (selection execution paths),
+``TRANSFER_STATS`` (result copies to the host) and ``PLAN_STATS``
+(expression hash-consing + planner rewrites).
 """
 from repro.kernels import reset_kernel_stats
 
 from .assoc import Assoc
-from .assoc_tensor import AssocTensor, DISPATCH_STATS
+from .assoc_tensor import AssocTensor, DISPATCH_STATS, TRANSFER_STATS
 from .coo import (aggregate_runs, canonicalize_np, dedup_sorted_coo,
                   intersect_pairs_np, linearize_pairs_np, spgemm_np)
 from .dist_assoc import DistAssoc
@@ -48,6 +49,7 @@ def reset_all_stats():
     Covers ``UNION_STATS`` (and drops the keyspace-union cache),
     ``CACHE_STATS`` (selector compilation — counters only; compiled
     selectors stay warm), ``DISPATCH_STATS`` (selection execution paths),
+    ``TRANSFER_STATS`` (result copies to the host),
     ``PLAN_STATS`` (and drops the plan cache) and the kernels'
     ``KERNEL_STATS`` (impl resolutions per trace).  Tests get this
     between cases from the autouse fixture in ``tests/conftest.py``;
@@ -55,8 +57,9 @@ def reset_all_stats():
     """
     clear_union_cache()
     reset_cache_stats()
-    for k in DISPATCH_STATS:
-        DISPATCH_STATS[k] = 0
+    for stats in (DISPATCH_STATS, TRANSFER_STATS):
+        for k in stats:
+            stats[k] = 0
     reset_plan_stats()
     reset_kernel_stats()
 
@@ -80,5 +83,5 @@ __all__ = [
     "PLAN_STATS", "reset_plan_stats", "clear_plan_cache",
     "CACHE_STATS", "clear_compile_cache", "reset_cache_stats",
     "UNION_STATS", "clear_union_cache",
-    "DISPATCH_STATS",
+    "DISPATCH_STATS", "TRANSFER_STATS",
 ]

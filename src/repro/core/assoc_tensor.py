@@ -34,6 +34,7 @@ import numpy as np
 
 from .assoc import Assoc
 from repro.analysis.contracts import contract
+from repro.trace import span
 
 from .coo import SENT, dedup_sorted_coo
 from .expr import EwiseAdd, EwiseMul, MatMul, Select, Source
@@ -87,8 +88,15 @@ def coo_axis_mask_keep(idx: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
 # to pin the fast path.
 DISPATCH_STATS = {"range": 0, "multirange": 0, "hybrid": 0, "gather": 0}
 
+# Device-to-host result traffic: ``to_host_bytes``/``to_host_calls`` count
+# what ``to_assoc`` copies (whole-capacity arrays), ``entries_returned``
+# the entries the serve layer's ``format_result`` hands back — their ratio
+# is the copy's cost per answered entry.
+TRANSFER_STATS = {"to_host_bytes": 0, "to_host_calls": 0,
+                  "entries_returned": 0}
+
 # Dict += is a read-modify-write: serve workers bump these concurrently.
-_DISPATCH_LOCK = threading.Lock()
+_STATS_LOCK = threading.Lock()
 
 
 @lru_cache(maxsize=None)
@@ -100,8 +108,15 @@ def _canonicalize(combine):
 
 
 def _bump_dispatch(key: str) -> None:
-    with _DISPATCH_LOCK:
+    with _STATS_LOCK:
         DISPATCH_STATS[key] += 1
+
+
+def count_transfer(**counts: int) -> None:
+    """Add ``counts`` to ``TRANSFER_STATS``."""
+    with _STATS_LOCK:
+        for k, n in counts.items():
+            TRANSFER_STATS[k] += n
 
 
 def coo_compact(rows: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
@@ -206,10 +221,15 @@ class AssocTensor:
 
     def to_assoc(self) -> Assoc:
         """Download to the host paper-faithful representation."""
-        n = int(self.nnz)
-        r = np.asarray(self.rows)[:n]
-        c = np.asarray(self.cols)[:n]
-        v = np.asarray(self.vals)[:n]
+        with span("d4m.device_wait"):
+            n = int(self.nnz)
+            jax.block_until_ready((self.rows, self.cols, self.vals))
+        with span("d4m.to_host"):
+            r, c, v = (np.asarray(a) for a in (self.rows, self.cols,
+                                                self.vals))
+        count_transfer(to_host_bytes=r.nbytes + c.nbytes + v.nbytes,
+                       to_host_calls=1)
+        r, c, v = r[:n], c[:n], v[:n]
         row_keys = self.row_space.keys[r]
         col_keys = self.col_space.keys[c]
         if self.val_space is not None:
@@ -437,12 +457,18 @@ class AssocTensor:
         return AssocTensor(r, c, v, nnz,
                            self.row_space, self.col_space, self.val_space)
 
+    @staticmethod
+    def _bounds(row_range: Tuple[int, int],
+                col_range: Tuple[int, int]) -> jnp.ndarray:
+        """A rank box as the range kernel's device bounds."""
+        return jnp.asarray([row_range[0], row_range[1],
+                            col_range[0], col_range[1]], dtype=jnp.int32)
+
     def _range_keep(self, row_range: Tuple[int, int],
                     col_range: Tuple[int, int]) -> jnp.ndarray:
         """Keep mask for a rank box, via the shared Pallas range kernel."""
-        bounds = jnp.asarray([row_range[0], row_range[1],
-                              col_range[0], col_range[1]], dtype=jnp.int32)
-        return coo_range_keep(self.rows, self.cols, bounds)
+        return coo_range_keep(self.rows, self.cols,
+                              self._bounds(row_range, col_range))
 
     def _mask_keep(self, row_mask: jnp.ndarray,
                    col_mask: jnp.ndarray) -> jnp.ndarray:
@@ -491,36 +517,49 @@ class AssocTensor:
         * one axis boxable, the other scattered → the box calls AND one
           membership gather for the scattered axis;
         * both axes scattered → two membership gathers (no kernel).
+
+        Spans: ``d4m.selector`` covers the host work (selector compile,
+        box planning, bounds and mask uploads), ``d4m.keep`` the device
+        launches.
         """
         from .select import plan_boxes
 
-        rc, cc = self._compiled_pair(ij)
-        nr = max(len(self.row_space), 1)
-        nc = max(len(self.col_space), 1)
-        boxes, row_gather, col_gather = plan_boxes(rc, cc, nr, nc)
-        if row_gather and col_gather:
-            _bump_dispatch("gather")
-            return self._mask_keep(*self._device_masks(rc, cc))
-        if len(boxes) > 1:
-            _bump_dispatch("multirange")
-        elif row_gather or col_gather:
-            _bump_dispatch("hybrid")
-        else:
-            _bump_dispatch("range")
-        keep = self._range_keep((int(boxes[0][0]), int(boxes[0][1])),
-                                (int(boxes[0][2]), int(boxes[0][3])))
-        for b in boxes[1:]:
-            keep = keep | self._range_keep((int(b[0]), int(b[1])),
-                                           (int(b[2]), int(b[3])))
-        # membership mask built (and uploaded) ONLY for a scattered axis —
-        # boxed axes are already handled by the kernel bounds
-        if row_gather:
-            keep = keep & coo_axis_mask_keep(
-                self.rows, jnp.asarray(np.ascontiguousarray(rc.mask())))
-        if col_gather:
-            keep = keep & coo_axis_mask_keep(
-                self.cols, jnp.asarray(np.ascontiguousarray(cc.mask())))
-        return keep
+        with span("d4m.selector"):
+            rc, cc = self._compiled_pair(ij)
+            nr = max(len(self.row_space), 1)
+            nc = max(len(self.col_space), 1)
+            boxes, row_gather, col_gather = plan_boxes(rc, cc, nr, nc)
+            if row_gather and col_gather:
+                _bump_dispatch("gather")
+                row_mask, col_mask = self._device_masks(rc, cc)
+            else:
+                if len(boxes) > 1:
+                    _bump_dispatch("multirange")
+                elif row_gather or col_gather:
+                    _bump_dispatch("hybrid")
+                else:
+                    _bump_dispatch("range")
+                bounds = [self._bounds((int(b[0]), int(b[1])),
+                                       (int(b[2]), int(b[3])))
+                          for b in boxes]
+                # membership mask built (and uploaded) ONLY for a
+                # scattered axis — boxed axes are already handled by the
+                # kernel bounds
+                row_mask = (jnp.asarray(np.ascontiguousarray(rc.mask()))
+                            if row_gather else None)
+                col_mask = (jnp.asarray(np.ascontiguousarray(cc.mask()))
+                            if col_gather else None)
+        with span("d4m.keep"):
+            if row_gather and col_gather:
+                return self._mask_keep(row_mask, col_mask)
+            keep = coo_range_keep(self.rows, self.cols, bounds[0])
+            for b in bounds[1:]:
+                keep = keep | coo_range_keep(self.rows, self.cols, b)
+            if row_mask is not None:
+                keep = keep & coo_axis_mask_keep(self.rows, row_mask)
+            if col_mask is not None:
+                keep = keep & coo_axis_mask_keep(self.cols, col_mask)
+            return keep
 
     @contract(collectives=0,
               note="device selection: range kernel / masks, never dense")
@@ -531,7 +570,10 @@ class AssocTensor:
 
     def _select_eager(self, ij) -> "AssocTensor":
         """Physical selection (the executor's device backend)."""
-        return self._compact(self._selection_keep(ij))
+        with span("d4m.select"):
+            keep = self._selection_keep(ij)
+            with span("d4m.compact"):
+                return self._compact(keep)
 
     @contract(collectives=0,
               note="in-place value overwrite over stored entries")

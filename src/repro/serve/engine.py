@@ -31,6 +31,14 @@ over ingest tables bind their merge-on-read snapshot at execution time.
 When the registry holds ingest tables the engine also runs a background
 :class:`~repro.ingest.Compactor`.
 
+Each request carries a :class:`repro.trace.Record` of its ``d4m.*`` spans:
+the HTTP thread adds wire decoding to it, the worker everything from
+dequeue to result.  A response's ``timing`` holds ``queue_s``, ``exec_s``
+and ``total_s`` and, from the spans, ``decode_s`` (wire decode),
+``selector_s`` (host selector compile and uploads), ``wait_s`` (waiting
+for the device's results), ``to_host_s`` (copying them to the host) and
+``format_s`` (building the JSON payload, copies excluded).
+
 The execution entry point :func:`serve_execute` carries a ``@contract``:
 shard-local serve queries inherit the zero-collective / never-densify
 budgets of the ops they dispatch, and ``tools/d4mcheck`` sweeps the serve
@@ -47,6 +55,7 @@ import numpy as np
 
 from repro.analysis.contracts import contract
 from repro.distributed.metrics import MetricsStore
+from repro.trace import Record, recording, span
 
 from .registry import TableRegistry
 from .wire import WireError, from_wire, ingest_from_wire, table_names
@@ -75,6 +84,14 @@ def serve_execute(expr):
     return expr.collect()
 
 
+# response ``timing`` field ← (span, whether only the span's own time)
+_STAGES = (("decode_s", "d4m.decode", False),
+           ("selector_s", "d4m.selector", False),
+           ("wait_s", "d4m.device_wait", False),
+           ("to_host_s", "d4m.to_host", False),
+           ("format_s", "d4m.format", True))
+
+
 def format_result(res, limit: Optional[int] = None) -> Dict[str, Any]:
     """Layer-native result → JSON-safe payload.
 
@@ -82,9 +99,15 @@ def format_result(res, limit: Optional[int] = None) -> Dict[str, Any]:
     is small by design; resident operands never move), reductions return
     dense vectors or scalars.
     """
+    with span("d4m.format"):
+        return _format(res, limit)
+
+
+def _format(res, limit):
     import jax.numpy as jnp
 
     from repro.core import Assoc, AssocTensor, DistAssoc
+    from repro.core.assoc_tensor import count_transfer
 
     if isinstance(res, (AssocTensor, DistAssoc)):
         res = res.to_assoc()
@@ -96,6 +119,7 @@ def format_result(res, limit: Optional[int] = None) -> Dict[str, Any]:
         truncated = limit is not None and n > limit
         if truncated:
             r, c, v = r[:limit], c[:limit], v[:limit]
+        count_transfer(entries_returned=len(r))
         return {"kind": "triples", "nnz": n,
                 "rows": [x.item() if hasattr(x, "item") else x
                          for x in r.tolist()],
@@ -122,14 +146,15 @@ class _Request:
 
     __slots__ = ("payload", "expr", "options", "batch_key", "t_enqueue",
                  "event", "result", "error", "timing", "batch_size",
-                 "kind", "data")
+                 "kind", "data", "spans")
 
-    def __init__(self, payload, expr, options, batch_key, *,
+    def __init__(self, payload, expr, options, batch_key, spans: Record, *,
                  kind: str = "query", data=None):
         self.payload = payload
         self.expr = expr
         self.options = options
         self.batch_key = batch_key
+        self.spans = spans
         self.kind = kind
         self.data = data
         self.t_enqueue = time.perf_counter()
@@ -173,7 +198,6 @@ class Engine:
         self._stores = [MetricsStore("sum") for _ in range(self.workers)]
         self._latencies: deque = deque(maxlen=2048)   # recent, for p50/p99
         self._lat_lock = threading.Lock()
-        self.t_start = time.time()
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "Engine":
@@ -236,14 +260,16 @@ class Engine:
         they read reflects mutations admitted ahead of them."""
         if not self._started:
             raise RuntimeError("engine not started")
-        from_wire(payload, resolve=None)        # structural validation first
-        key = self._admission_key(payload)      # then table-name checks
-        tables = key[1]
-        if any(self.registry.is_ingest(n) for n in tables):
-            expr = None                         # bind at execution time
-        else:
-            expr = from_wire(payload, resolve=self.registry.resolve)
-        req = _Request(payload, expr, dict(options or {}), key)
+        rec = Record()
+        with recording(rec), span("d4m.decode"):
+            from_wire(payload, resolve=None)    # structural validation first
+            key = self._admission_key(payload)  # then table-name checks
+            tables = key[1]
+            if any(self.registry.is_ingest(n) for n in tables):
+                expr = None                     # bind at execution time
+            else:
+                expr = from_wire(payload, resolve=self.registry.resolve)
+        req = _Request(payload, expr, dict(options or {}), key, rec)
         with self._cv:
             self._queue.append(req)
             self._cv.notify()
@@ -265,10 +291,12 @@ class Engine:
         ingest with an independent query."""
         if not self._started:
             raise RuntimeError("engine not started")
-        name, rows, cols, vals = ingest_from_wire(payload)
-        self.registry.ingest_table(name)        # raises if not ingestable
+        rec = Record()
+        with recording(rec), span("d4m.decode"):
+            name, rows, cols, vals = ingest_from_wire(payload)
+            self.registry.ingest_table(name)    # raises if not ingestable
         req = _Request(payload, None, dict(options or {}),
-                       ("ingest", name), kind="ingest",
+                       ("ingest", name), rec, kind="ingest",
                        data=(name, rows, cols, vals))
         with self._cv:
             self._queue.append(req)
@@ -332,45 +360,52 @@ class Engine:
             store.log(0, {"batches": 1.0, "batch_n": float(len(batch))})
             for req in batch:
                 req.batch_size = len(batch)
-                t0 = time.perf_counter()
-                try:
-                    if req.kind == "ingest":
-                        name, rows, cols, vals = req.data
-                        table = self.registry.ingest_table(name)
-                        out = table.insert(rows, cols, vals)
-                        body = {"kind": "ingest", "table": name,
-                                "version": table.version, **out}
-                        store.log(0, {"ingests": 1.0,
-                                      "ingest_triples":
-                                          float(out["accepted"])})
-                    else:
-                        if req.expr is None:    # ingest-table query: bind now
-                            req.expr = from_wire(
-                                req.payload, resolve=self.registry.resolve)
-                        res = serve_execute(req.expr)
-                        limit = req.options.get("limit", self.default_limit)
-                        body = format_result(res, limit=limit)
-                except (WireError, QueryError) as exc:
-                    req.error = exc
-                except Exception as exc:   # execution-time type errors etc.
-                    req.error = QueryError("execution_error",
-                                           f"{type(exc).__name__}: {exc}")
-                else:
-                    t1 = time.perf_counter()
-                    req.timing = {
-                        "queue_s": round(t0 - req.t_enqueue, 6),
-                        "exec_s": round(t1 - t0, 6),
-                        "total_s": round(t1 - req.t_enqueue, 6),
-                    }
-                    req.result = {"result": body, "timing": req.timing,
-                                  "batch": req.batch_size}
-                t_total = time.perf_counter() - req.t_enqueue
-                store.log(0, {"requests": 1.0,
-                              "errors": 1.0 if req.error else 0.0,
-                              "latency_s": t_total})
-                with self._lat_lock:
-                    self._latencies.append(t_total)
-                req.event.set()
+                with recording(req.spans), span("d4m.execute"):
+                    self._execute(req, store)
+
+    def _execute(self, req: _Request, store: MetricsStore) -> None:
+        """Run one admitted request and hand its result to the waiter."""
+        t0 = time.perf_counter()
+        try:
+            if req.kind == "ingest":
+                name, rows, cols, vals = req.data
+                table = self.registry.ingest_table(name)
+                out = table.insert(rows, cols, vals)
+                body = {"kind": "ingest", "table": name,
+                        "version": table.version, **out}
+                store.log(0, {"ingests": 1.0,
+                              "ingest_triples": float(out["accepted"])})
+            else:
+                if req.expr is None:    # ingest-table query: bind now
+                    with span("d4m.decode"):
+                        req.expr = from_wire(req.payload,
+                                             resolve=self.registry.resolve)
+                res = serve_execute(req.expr)
+                limit = req.options.get("limit", self.default_limit)
+                body = format_result(res, limit=limit)
+        except (WireError, QueryError) as exc:
+            req.error = exc
+        except Exception as exc:   # execution-time type errors etc.
+            req.error = QueryError("execution_error",
+                                   f"{type(exc).__name__}: {exc}")
+        t1 = time.perf_counter()
+        if req.error is None:
+            spans = req.spans
+            req.timing = {
+                "queue_s": round(t0 - req.t_enqueue, 6),
+                "exec_s": round(t1 - t0, 6),
+                "total_s": round(t1 - req.t_enqueue, 6),
+                **{field: round((spans.own if own else spans.total)
+                                .get(name, 0.0), 6)
+                   for field, name, own in _STAGES},
+            }
+            req.result = {"result": body, "timing": req.timing,
+                          "batch": req.batch_size}
+        store.log(0, {"requests": 1.0,
+                      "errors": 1.0 if req.error else 0.0})
+        with self._lat_lock:
+            self._latencies.append(t1 - req.t_enqueue)
+        req.event.set()
 
     # -- telemetry ----------------------------------------------------------
     def metrics(self) -> MetricsStore:
@@ -383,7 +418,7 @@ class Engine:
     def stats(self) -> Dict[str, Any]:
         """The /stats body: server counters + core telemetry dicts."""
         from repro.core import (CACHE_STATS, DISPATCH_STATS, PLAN_STATS,
-                                UNION_STATS)
+                                TRANSFER_STATS, UNION_STATS)
         from repro.kernels import KERNEL_STATS
 
         merged = self.metrics()
@@ -397,18 +432,15 @@ class Engine:
         if lats:
             server["p50_s"] = float(np.percentile(lats, 50))
             server["p99_s"] = float(np.percentile(lats, 99))
-        n_req = server.get("requests", 0.0)
         if server.get("batches"):
             server["batch_mean"] = server["batch_n"] / server["batches"]
-        server["uptime_s"] = time.time() - self.t_start
-        if n_req and server.get("latency_s") is not None:
-            server["latency_mean_s"] = server["latency_s"] / n_req
         out = {
             "server": server,
             "plan": dict(PLAN_STATS),
             "cache": dict(CACHE_STATS),
             "union": dict(UNION_STATS),
             "dispatch": dict(DISPATCH_STATS),
+            "transfer": dict(TRANSFER_STATS),
             "kernels": dict(KERNEL_STATS),
             "queue_depth": len(self._queue),
             "workers": self.workers,

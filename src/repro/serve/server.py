@@ -14,10 +14,14 @@ Endpoints (all JSON):
 * ``GET /tables``  — registry listing (name/layer/shape/nnz per table).
 * ``GET /stats``   — server request/latency/batch metrics ⊕-merged across
   workers + the core telemetry dicts (``plan``/``cache``/``union``/
-  ``dispatch``) — ``plan.plan_hits`` is the cross-request plan-cache
-  signal.
+  ``dispatch``/``transfer``) — ``plan.plan_hits`` is the cross-request
+  plan-cache signal.
 * ``POST /stats/reset`` — zero the measurement window (bench harness).
 * ``GET /health``  — liveness + table count.
+
+Every ``POST`` runs inside a ``d4m.request`` span and writes its answer
+inside ``d4m.encode`` (:mod:`repro.trace`), so a ``jax.profiler`` trace of
+the server shows each request beside the device work it launched.
 
 CLI::
 
@@ -36,6 +40,8 @@ import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from pathlib import Path
 from typing import Optional
+
+from repro.trace import span
 
 from .engine import Engine, QueryError
 from .registry import TableRegistry
@@ -77,12 +83,13 @@ class _Handler(BaseHTTPRequestHandler):
         return self.server.engine          # type: ignore[attr-defined]
 
     def _send(self, status: int, body: dict) -> None:
-        data = json.dumps(body).encode()
-        self.send_response(status)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Content-Length", str(len(data)))
-        self.end_headers()
-        self.wfile.write(data)
+        with span("d4m.encode"):
+            data = json.dumps(body).encode()
+            self.send_response(status)
+            self.send_header("Content-Type", "application/json")
+            self.send_header("Content-Length", str(len(data)))
+            self.end_headers()
+            self.wfile.write(data)
 
     def _error(self, status: int, code: str, message: str) -> None:
         self._send(status, {"error": {"code": code, "message": message}})
@@ -103,6 +110,10 @@ class _Handler(BaseHTTPRequestHandler):
             self._error(500, "internal", f"{type(exc).__name__}: {exc}")
 
     def do_POST(self) -> None:  # noqa: N802
+        with span("d4m.request"):
+            self._post()
+
+    def _post(self) -> None:
         try:
             if self.path == "/stats/reset":
                 self.engine.reset_stats()

@@ -84,6 +84,19 @@ def test_extract_rank_range():
     assert sub.to_assoc().to_dict() == {("a", "x"): 1.0, ("b", "x"): 2.0}
 
 
+def test_to_assoc_counts_whole_capacity_copies():
+    from repro.core import TRANSFER_STATS, reset_all_stats
+    cap = 40
+    dev = AssocTensor.from_triples(["a", "b"], ["x", "y"], [1.0, 2.0],
+                                   capacity=cap)
+    reset_all_stats()
+    assert dev.to_assoc().nnz() == 2
+    assert TRANSFER_STATS == {"to_host_bytes": 12 * cap,
+                              "to_host_calls": 1, "entries_returned": 0}
+    reset_all_stats()
+    assert set(TRANSFER_STATS.values()) == {0}
+
+
 def test_reduce_rows():
     dev = AssocTensor.from_triples(["a", "a", "b"], ["x", "y", "x"],
                                    [1.0, 2.0, 4.0], aggregate="sum",

@@ -255,6 +255,51 @@ def test_http_execution_error_is_422(client):
     assert ei.value.code == "execution_error"
 
 
+STAGES = ("decode_s", "selector_s", "wait_s", "to_host_s", "format_s")
+SPANS = ("d4m.request", "d4m.decode", "d4m.encode", "d4m.execute",
+         "d4m.select", "d4m.selector", "d4m.keep", "d4m.compact",
+         "d4m.format", "d4m.device_wait", "d4m.to_host")
+
+
+def _one_row(registry):
+    key = str(registry.get("edges").row_space.keys[0])
+    return TableRef("edges")[Keys([key]), :]
+
+
+def test_http_one_row_read_times_its_stages(client, registry):
+    client.reset_stats()
+    out = client.query(_one_row(registry))
+    t = out["timing"]
+    assert out["result"]["nnz"] >= 1
+    assert all(t[k] >= 0 for k in STAGES)
+    assert t["selector_s"] > 0 and t["wait_s"] > 0 and t["to_host_s"] > 0
+    assert sum(t[k] for k in STAGES) <= t["total_s"]
+    assert t["exec_s"] <= t["total_s"]
+    tr = client.stats()["transfer"]
+    cap = registry.get("edges").capacity
+    assert tr == {"to_host_bytes": 12 * cap, "to_host_calls": 1,
+                  "entries_returned": out["result"]["nnz"]}
+
+
+def test_profiler_trace_of_a_read_holds_every_span(client, registry,
+                                                   tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    client.query(_one_row(registry))                 # compiled before
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        client.query(_one_row(registry))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    names = {ev.name for p in ProfileData.from_file(str(path)).planes
+             for ln in p.lines for ev in ln.events}
+    assert set(SPANS) <= names
+
+
 def test_http_404(client):
     with pytest.raises(ServerError) as ei:
         client._request("/nope")
