@@ -17,13 +17,15 @@ Telemetry counters (and their reset helpers) are exported together so
 benchmarks and tests can assert a fast path actually fired:
 ``CACHE_STATS`` (selector compilation), ``UNION_STATS`` (keyspace-union
 memoization), ``DISPATCH_STATS`` (selection execution paths),
-``TRANSFER_STATS`` (result copies to the host) and ``PLAN_STATS``
-(expression hash-consing + planner rewrites).
+``TRANSFER_STATS`` (result copies to the host), ``COMPACT_STATS``
+(selection compaction paths) and ``PLAN_STATS`` (expression
+hash-consing + planner rewrites).
 """
 from repro.kernels import reset_kernel_stats
 
 from .assoc import Assoc
-from .assoc_tensor import AssocTensor, DISPATCH_STATS, TRANSFER_STATS
+from .assoc_tensor import (AssocTensor, COMPACT_STATS, DISPATCH_STATS,
+                           TRANSFER_STATS)
 from .coo import (aggregate_runs, canonicalize_np, dedup_sorted_coo,
                   intersect_pairs_np, linearize_pairs_np, spgemm_np)
 from .dist_assoc import DistAssoc
@@ -50,6 +52,7 @@ def reset_all_stats():
     ``CACHE_STATS`` (selector compilation — counters only; compiled
     selectors stay warm), ``DISPATCH_STATS`` (selection execution paths),
     ``TRANSFER_STATS`` (result copies to the host),
+    ``COMPACT_STATS`` (selection compaction paths),
     ``PLAN_STATS`` (and drops the plan cache) and the kernels'
     ``KERNEL_STATS`` (impl resolutions per trace).  Tests get this
     between cases from the autouse fixture in ``tests/conftest.py``;
@@ -57,7 +60,7 @@ def reset_all_stats():
     """
     clear_union_cache()
     reset_cache_stats()
-    for stats in (DISPATCH_STATS, TRANSFER_STATS):
+    for stats in (DISPATCH_STATS, TRANSFER_STATS, COMPACT_STATS):
         for k in stats:
             stats[k] = 0
     reset_plan_stats()
@@ -83,5 +86,5 @@ __all__ = [
     "PLAN_STATS", "reset_plan_stats", "clear_plan_cache",
     "CACHE_STATS", "clear_compile_cache", "reset_cache_stats",
     "UNION_STATS", "clear_union_cache",
-    "DISPATCH_STATS", "TRANSFER_STATS",
+    "DISPATCH_STATS", "TRANSFER_STATS", "COMPACT_STATS",
 ]

@@ -56,7 +56,8 @@ def _round_up(x: int, m: int) -> int:
 # Shared by AssocTensor's methods AND DistAssoc's shard_map bodies (which
 # operate on raw per-shard arrays, not pytree objects): one implementation
 # of the keep mask and the sentinel-blank + lexsort compaction, so the
-# layers cannot drift apart.
+# layers cannot drift apart.  Eager AssocTensor selections compact small
+# results into a result-sized buffer instead (``_compact_sized``).
 
 def coo_range_keep(rows: jnp.ndarray, cols: jnp.ndarray,
                    bounds: jnp.ndarray) -> jnp.ndarray:
@@ -95,6 +96,21 @@ DISPATCH_STATS = {"range": 0, "multirange": 0, "hybrid": 0, "gather": 0}
 TRANSFER_STATS = {"to_host_bytes": 0, "to_host_calls": 0,
                   "entries_returned": 0}
 
+# Selection compactions by path (``AssocTensor._compact``; a traced one
+# counts once per trace): ``sized`` moved the kept entries into a
+# result-sized buffer (``_compact_sized``), ``full`` re-sorted the whole
+# capacity (``coo_compact``: traced keep masks, small tables, large
+# results).
+COMPACT_STATS = {"sized": 0, "full": 0}
+
+# The sized result buffer is a power of two of at least ``_SIZED_MIN``
+# slots (one compiled program serves every small result of a table), and
+# at most capacity / ``_SIZED_MAX_SHARE``: past that, ``k`` binary
+# searches stop paying and a full-size result gains nothing from a
+# smaller buffer.
+_SIZED_MIN = 256
+_SIZED_MAX_SHARE = 8
+
 # Dict += is a read-modify-write: serve workers bump these concurrently.
 _STATS_LOCK = threading.Lock()
 
@@ -121,12 +137,36 @@ def count_transfer(**counts: int) -> None:
 
 def coo_compact(rows: jnp.ndarray, cols: jnp.ndarray, vals: jnp.ndarray,
                 keep: jnp.ndarray):
-    """Keep-masked triples → canonical sorted/sentinel-padded form."""
+    """Keep-masked triples → canonical sorted/sentinel-padded form.
+
+    Static-shape and order-free: the kept triples need not be in (row,
+    col) order, and the result keeps the input's capacity — what traced
+    selections, ``DistAssoc``'s shard bodies and the ingest merge need.
+    """
     r = jnp.where(keep, rows, SENT)
     c = jnp.where(keep, cols, SENT)
     v = jnp.where(keep, vals, 0.0)
     order = jnp.lexsort((c, r))
     return r[order], c[order], v[order], keep.sum().astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnums=4)
+def _compact_sized(rows, cols, vals, keep, k: int):
+    """The kept triples moved, in stored order, into ``k`` SENT-padded
+    slots: the first ``k`` slots of :func:`coo_compact`'s result when the
+    input is canonical (a keep mask filters a sorted table pointwise, so
+    the kept entries are already sorted) and at most ``k`` are kept.
+
+    The j-th kept entry sits where the prefix count of ``keep`` first
+    reaches j + 1: ``k`` binary searches into the count and three
+    ``k``-element gathers, no sort.
+    """
+    cs = jnp.cumsum(keep, dtype=jnp.int32)
+    pos = jnp.searchsorted(cs, jnp.arange(1, k + 1, dtype=jnp.int32))
+    pos = jnp.minimum(pos, keep.shape[0] - 1)
+    ok = jnp.arange(k) < cs[-1]
+    return (jnp.where(ok, rows[pos], SENT), jnp.where(ok, cols[pos], SENT),
+            jnp.where(ok, vals[pos], 0.0), cs[-1])
 
 
 @jax.tree_util.register_pytree_node_class
@@ -452,8 +492,29 @@ class AssocTensor:
     # densifies.
 
     def _compact(self, keep: jnp.ndarray) -> "AssocTensor":
-        """Keep-masked triples → canonical sorted/sentinel-padded form."""
-        r, c, v, nnz = coo_compact(self.rows, self.cols, self.vals, keep)
+        """Keep-masked triples → canonical sorted/sentinel-padded form.
+
+        An eager ``keep`` has its count read to the host; a result that
+        fits a buffer of at most capacity / ``_SIZED_MAX_SHARE`` slots is
+        moved into one (:func:`_compact_sized`), in stored order.  A
+        traced ``keep`` (no count can be read) and a larger result take
+        the whole-capacity :func:`coo_compact`.
+        """
+        k = None
+        if (not isinstance(keep, jax.core.Tracer)
+                and self.capacity // _SIZED_MAX_SHARE >= _SIZED_MIN):
+            with span("d4m.device_wait"):
+                n = int(keep.sum())
+            k = max(_SIZED_MIN, 1 << (n - 1).bit_length())
+            if k > self.capacity // _SIZED_MAX_SHARE:
+                k = None
+        if k is None:
+            r, c, v, nnz = coo_compact(self.rows, self.cols, self.vals, keep)
+        else:
+            r, c, v, nnz = _compact_sized(self.rows, self.cols, self.vals,
+                                          keep, k)
+        with _STATS_LOCK:
+            COMPACT_STATS["full" if k is None else "sized"] += 1
         return AssocTensor(r, c, v, nnz,
                            self.row_space, self.col_space, self.val_space)
 
@@ -511,9 +572,10 @@ class AssocTensor:
         * both axes contiguous → ONE Pallas range-mask kernel call;
         * a multi-interval ``Match``/``Where``/``Keys`` whose hits form ≤4
           rank boxes → one range-kernel call per box, OR-composed (the
-          boxes are disjoint interval runs, so the OR is exact and the
-          single downstream compaction is the only sort — no merge of
-          extracted lists needed);
+          boxes are disjoint interval runs, so the OR is exact, and like
+          every keep mask here it filters the stored (row, col) order
+          pointwise — the compaction keeps that order, with no merge of
+          extracted lists and no sort);
         * one axis boxable, the other scattered → the box calls AND one
           membership gather for the scattered axis;
         * both axes scattered → two membership gathers (no kernel).

@@ -417,8 +417,8 @@ class Engine:
 
     def stats(self) -> Dict[str, Any]:
         """The /stats body: server counters + core telemetry dicts."""
-        from repro.core import (CACHE_STATS, DISPATCH_STATS, PLAN_STATS,
-                                TRANSFER_STATS, UNION_STATS)
+        from repro.core import (CACHE_STATS, COMPACT_STATS, DISPATCH_STATS,
+                                PLAN_STATS, TRANSFER_STATS, UNION_STATS)
         from repro.kernels import KERNEL_STATS
 
         merged = self.metrics()
@@ -441,6 +441,7 @@ class Engine:
             "union": dict(UNION_STATS),
             "dispatch": dict(DISPATCH_STATS),
             "transfer": dict(TRANSFER_STATS),
+            "compact": dict(COMPACT_STATS),
             "kernels": dict(KERNEL_STATS),
             "queue_depth": len(self._queue),
             "workers": self.workers,

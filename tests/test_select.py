@@ -515,3 +515,160 @@ def test_few_runs_stay_on_multirange(wide_layers, layer):
     kind, got = _dispatch_of(arr, (rows2, cols2))
     assert kind == "multirange"
     assert _dict_close(got, want), (got, want)
+
+
+# ---------------------------------------------------------------------------
+# result-sized compaction of eager device selections
+# ---------------------------------------------------------------------------
+#
+# Eager selections of a table whose capacity is at least 8 × 256 move the
+# kept entries into a power-of-two buffer of ≥256 slots, in stored order;
+# the whole-capacity lexsort (coo_compact) stays the reference.
+
+SIZED_ROWS = [f"r{i:04d}" for i in range(2000)]
+SIZED_COLS = [f"c{i:03d}" for i in range(600)]
+
+
+def _sized_triples(string_vals):
+    rows, cols = [], []
+    for i in range(len(SIZED_ROWS)):
+        for j in range(1 + i % 4):
+            rows.append(SIZED_ROWS[i])
+            cols.append(SIZED_COLS[(7 * i + 13 * j) % len(SIZED_COLS)])
+    rows, cols = np.asarray(rows), np.asarray(cols)
+    vals = (np.arange(len(rows)) * 37) % 97 + 1.0
+    if string_vals:
+        vals = np.asarray([f"v{int(v):02d}" for v in vals])
+    return rows, cols, vals
+
+
+@pytest.fixture(scope="module", params=["numeric", "string"])
+def sized_table(request):
+    rows, cols, vals = _sized_triples(request.param == "string")
+    return (Assoc(rows, cols, vals),
+            AssocTensor.from_triples(rows, cols, vals, capacity=8192))
+
+
+# one selector pair per dispatch kind, as analysis/probes._selector_kinds
+SIZED_KINDS = [
+    ("range", (Range(SIZED_ROWS[100], SIZED_ROWS[160]), All())),
+    ("multirange", (Keys(SIZED_ROWS[10:20] + SIZED_ROWS[300:310]), All())),
+    ("hybrid", (Range(SIZED_ROWS[100], SIZED_ROWS[400]),
+                Keys(SIZED_COLS[::5][:40]))),
+    ("gather", (Keys(SIZED_ROWS[::5][:60]), Keys(SIZED_COLS[::3][:100]))),
+]
+
+
+def _whole_capacity(t, ij):
+    """The selection as the whole-capacity lexsort compacts it."""
+    from repro.core.assoc_tensor import coo_compact
+    r, c, v, nnz = coo_compact(t.rows, t.cols, t.vals,
+                               t._selection_keep(ij))
+    return AssocTensor(r, c, v, nnz, t.row_space, t.col_space, t.val_space)
+
+
+def _same_selection(got, ref):
+    """Equal entries in equal order, equal nnz and keyspaces: ``got``'s
+    slots are the first ``got.capacity`` of the reference's."""
+    k = got.capacity
+    assert int(got.nnz) == int(ref.nnz)
+    assert got.row_space is ref.row_space and got.col_space is ref.col_space
+    assert got.val_space is ref.val_space
+    for a, b in ((got.rows, ref.rows), (got.cols, ref.cols),
+                 (got.vals, ref.vals)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[:k])
+    assert got.to_assoc().to_dict() == ref.to_assoc().to_dict()
+
+
+@pytest.mark.parametrize("kind,ij", SIZED_KINDS,
+                         ids=[k for k, _ in SIZED_KINDS])
+def test_sized_compaction_matches_whole_capacity(sized_table, kind, ij):
+    from repro.core import COMPACT_STATS, DISPATCH_STATS, reset_all_stats
+    host, dev = sized_table
+    ref = _whole_capacity(dev, ij)
+    reset_all_stats()
+    got = dev[ij]
+    assert [k for k, v in DISPATCH_STATS.items() if v] == [kind]
+    assert COMPACT_STATS == {"sized": 1, "full": 0}
+    assert got.capacity == 256 and 0 < int(got.nnz) <= 256
+    _same_selection(got, ref)
+    assert got.to_assoc().to_dict() == host[ij].to_dict()
+
+
+ONE_ROWS = [f"q{i:04d}" for i in range(5000)]
+
+
+@pytest.fixture(scope="module")
+def one_per_row():
+    """5000 rows of one entry each in a 16384-slot table: a row range of
+    n keys keeps exactly n entries; results above 16384 / 8 = 2048 slots
+    take the whole-capacity path."""
+    rows = np.asarray(ONE_ROWS)
+    cols = np.asarray([f"c{i % 7}" for i in range(len(rows))])
+    vals = np.arange(1.0, len(rows) + 1.0)
+    return (Assoc(rows, cols, vals),
+            AssocTensor.from_triples(rows, cols, vals, capacity=16384))
+
+
+@pytest.mark.parametrize("count,path,cap", [
+    (0, "sized", 256), (1, "sized", 256), (256, "sized", 256),
+    (257, "sized", 512), (2048, "sized", 2048), (2049, "full", 16384)])
+def test_sized_compaction_buckets_and_falls_back(one_per_row, count, path,
+                                                 cap):
+    from repro.core import COMPACT_STATS, reset_all_stats
+    host, dev = one_per_row
+    ij = ((Range(ONE_ROWS[10], ONE_ROWS[9 + count]) if count
+           else Keys(["absent"])), All())
+    ref = _whole_capacity(dev, ij)
+    reset_all_stats()
+    got = dev[ij]
+    assert COMPACT_STATS == {"sized": int(path == "sized"),
+                             "full": int(path == "full")}
+    assert got.capacity == cap and int(got.nnz) == count
+    _same_selection(got, ref)
+    assert got.to_assoc().to_dict() == host[ij].to_dict()
+
+
+def test_selection_of_sized_selection(one_per_row):
+    from repro.core import COMPACT_STATS, reset_all_stats
+    host, dev = one_per_row
+    outer = (Range(ONE_ROWS[100], ONE_ROWS[1599]), All())
+    inner = (Range(ONE_ROWS[400], ONE_ROWS[449]), "c1,c3,")
+    reset_all_stats()
+    first = dev[outer]
+    assert first.capacity == 2048
+    got = first[inner]
+    assert COMPACT_STATS == {"sized": 2, "full": 0}
+    _same_selection(got, _whole_capacity(first, inner))
+    assert got.to_assoc().to_dict() == host[outer][inner].to_dict()
+
+
+def test_sized_selection_feeds_add_and_matmul(one_per_row):
+    host, dev = one_per_row
+    a_sel = (Range(ONE_ROWS[0], ONE_ROWS[99]), All())
+    b_sel = (Range(ONE_ROWS[50], ONE_ROWS[149]), All())
+    a, b = dev[a_sel], dev[b_sel]
+    ha, hb = host[a_sel], host[b_sel]
+    assert a.capacity == b.capacity == 256
+    assert _dict_close((a + b).to_assoc().to_dict(), (ha + hb).to_dict())
+    assert _dict_close((a @ b.transpose()).to_assoc().to_dict(),
+                       (ha @ hb.transpose()).to_dict())
+
+
+def test_traced_selection_keeps_whole_capacity_compaction(one_per_row):
+    from repro.analysis.probes import PROBES
+    from repro.core import COMPACT_STATS, reset_all_stats
+    _, dev = one_per_row
+    ij = (Range(ONE_ROWS[10], ONE_ROWS[29]), All())
+    eager = dev[ij]
+    reset_all_stats()
+    traced = jax.jit(lambda x: x._select_eager(ij))(dev)
+    assert COMPACT_STATS == {"sized": 0, "full": 1}
+    assert traced.capacity == dev.capacity
+    _same_selection(eager, traced)
+    # the HLO probes lower every dispatch kind with no host read
+    hlo = dict(PROBES["AssocTensor.__getitem__"]())
+    assert set(hlo) == {"range", "multirange", "hybrid", "gather"}
+    assert all("sort" in text for text in hlo.values())
+    reset_all_stats()
+    assert COMPACT_STATS == {"sized": 0, "full": 0}

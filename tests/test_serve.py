@@ -281,6 +281,14 @@ def test_http_one_row_read_times_its_stages(client, registry):
                   "entries_returned": out["result"]["nnz"]}
 
 
+def test_http_stats_counts_compaction_paths(client, registry):
+    client.reset_stats()
+    assert client.stats()["compact"] == {"sized": 0, "full": 0}
+    client.query(_one_row(registry))
+    # a 512-entry table is below 8 × 256 slots: whole-capacity compaction
+    assert client.stats()["compact"] == {"sized": 0, "full": 1}
+
+
 def test_profiler_trace_of_a_read_holds_every_span(client, registry,
                                                    tmp_path):
     import jax

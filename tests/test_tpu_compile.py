@@ -113,3 +113,14 @@ def test_sorted_merge_compiles(one_chip, fn):
     text = _compiled_text(one_chip, fn,
                           [((N_BASE,), I32), ((N_DELTA,), I32)])
     assert "tpu_custom_call" in text
+
+
+def test_sized_compaction_compiles_without_sort(one_chip):
+    # the eager selection's result-sized compaction at the n = 18 capacity:
+    # a prefix count, binary searches and 256-element gathers, no sort
+    from repro.core.assoc_tensor import _compact_sized
+    args = [jax.ShapeDtypeStruct((N_BASE,), d, sharding=one_chip)
+            for d in (I32, I32, F32, jnp.bool_)]
+    text = _compact_sized.lower(*args, 256).compile().as_text()
+    assert " sort(" not in text
+    assert "s32[256]" in text
