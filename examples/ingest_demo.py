@@ -3,11 +3,11 @@
 Boots the query server with one device-layer **ingest** table, then walks
 the LSM lifecycle end to end through the HTTP client:
 
-1. stream triple batches into ``POST /ingest`` (host-side delta buffer —
-   no device work, no re-canonicalize on the write path);
-2. query DURING ingest — reads see base ⊕ delta through the compiled
-   overlay merge (merge-on-read), repeated reads between mutations reuse
-   one merged snapshot;
+1. stream triple batches into ``POST /ingest`` (each batch folded once
+   into the table's device delta);
+2. query DURING ingest — reads see base ⊕ delta (merge-on-read): a
+   selection reads base and delta apart, other queries a merged snapshot
+   that repeated reads between mutations reuse;
 3. wait for the background compactor to fold the delta into a new base
    (``/stats`` shows ``delta_depth`` returning to 0 and ``compactions``
    ticking up), and check reads are unchanged by compaction;

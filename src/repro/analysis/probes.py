@@ -461,40 +461,53 @@ def _probe_dist_matmul_bsr():
 
 
 # --------------------------------------------------------------------------
-# Dynamic ingest (repro.ingest): the LSM write/read path.  The append
-# canonicalize and both merge-on-read programs must be zero-collective
-# (delta batches are pre-routed to their owning row shard on host) and
-# never densify (the overlay output is O(capb + capd), never O(nr·nc));
-# small COO capacities over 4096-rank keyspaces keep the detector sharp.
+# Dynamic ingest (repro.ingest): the LSM write/read path.  The batch fold
+# and both merge programs must be zero-collective (delta batches are
+# pre-routed to their owning row shard on host) and never densify (the
+# merged output is O(capb + capd), never O(nr·nc)); small COO capacities
+# over 4096-rank keyspaces keep the detector sharp.
 # --------------------------------------------------------------------------
+
+def _delta_sds(cap: int = _CAP):
+    import jax.numpy as jnp
+    return (*_b_triples_sds(cap), _sds((cap,), jnp.int32),
+            _sds((cap,), jnp.bool_))
+
 
 @probe_for("ingest.append")
 def _probe_ingest_append():
-    from repro.ingest.merge import _delta_canon_prog
+    import jax.numpy as jnp
+    from repro.ingest.merge import REMAP_SLOTS, _append_prog
 
-    r, c, v = _b_triples_sds()
-    yield "delta-canon", lower_hlo(_delta_canon_prog("sum"), r, c, v)
+    br, bc, _ = _b_triples_sds(4 * _CAP)
+    xr, xc, xv = _b_triples_sds(_CAP // 4)
+    q = _sds((_CAP // 4,), jnp.int32)
+    for remap, n in [("shift", REMAP_SLOTS), ("map", _NKEYS)]:
+        aux = _sds((n,), jnp.int32)
+        yield f"append-{remap}", lower_hlo(
+            _append_prog("sum", remap), br, bc, *_delta_sds(), xr, xc, xv,
+            q, q, aux, aux)
 
     def run():
-        _delta_canon_prog("sum")
+        _append_prog("sum", "shift")
 
     yield RetraceAudit(label="append-prog-cache", first=run, again=run,
-                       size=lambda: _delta_canon_prog.cache_info().currsize)
+                       size=lambda: _append_prog.cache_info().currsize)
 
 
 @probe_for("ingest.merge_read")
 def _probe_ingest_merge_read():
     import jax.numpy as jnp
-    from repro.ingest.merge import _merge_read_prog
+    from repro.ingest.merge import REMAP_SLOTS, _merge_read_prog
 
-    br, bc, bv = _b_triples_sds()
-    dr, dc, dv = _b_triples_sds()
-    prog = _merge_read_prog("sum")
-    yield "overlay-merge", lower_hlo(prog, br, bc, bv, dr, dc, dv,
-                                     _sds((), jnp.int32))
+    aux = _sds((REMAP_SLOTS,), jnp.int32)
+    prog = _merge_read_prog("sum", "shift")
+    yield "overlay-merge", lower_hlo(prog, *_b_triples_sds(4 * _CAP),
+                                     *_delta_sds(), aux, aux,
+                                     out_cap=4 * _CAP)
 
     def run():
-        _merge_read_prog("sum")
+        _merge_read_prog("sum", "shift")
 
     yield RetraceAudit(label="merge-prog-cache", first=run, again=run,
                        size=lambda: _merge_read_prog.cache_info().currsize)

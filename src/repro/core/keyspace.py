@@ -63,9 +63,11 @@ class KeySpace:
         # dtype.str + length disambiguate the fixed-width buffer: a plain
         # separator join would collide for keys containing the separator
         # (["a\x00b"] vs ["a", "b"]), and the digest is the sole identity
-        # for the union/compile caches
+        # for the union/compile caches.  The array's own buffer is hashed,
+        # not a ``tobytes()`` copy: hashlib reads it with the GIL released,
+        # so a large keyspace does not stall the process's other threads
         h = hashlib.sha1(f"{keys.dtype.str}:{len(keys)}:".encode())
-        h.update(keys.tobytes())
+        h.update(memoryview(np.ascontiguousarray(keys)).cast("B"))
         return h.hexdigest()
 
     @classmethod

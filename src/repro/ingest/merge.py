@@ -1,19 +1,22 @@
-"""Merge-on-read programs: base COO ⊕ delta overlay, on device.
+"""The ingest programs: a delta folded batch by batch, and base ⊕ delta.
 
-The LSM read path has three compiled programs, all zero-collective and
-never densifying (declared via ``@contract``, proven by ``d4mcheck``):
+Both are zero-collective and never densify (declared via ``@contract``,
+proven by ``d4mcheck``), and neither sorts anything larger than a batch:
 
-* :func:`_delta_canon_prog` — canonicalize a raw (unsorted, duplicated)
-  delta buffer into sorted merged COO: the device work an append batch
-  triggers at read time.  One :func:`~repro.core.coo.dedup_sorted_coo`
-  pass, nothing else.
-* :func:`_merge_read_prog` — single-device overlay merge.  Base is
-  already canonical (sorted by (row, col) ⇔ sorted by linearized key),
-  so after canonicalizing delta the union layout comes from the
-  ``sorted_merge`` rank-count kernel (:func:`overlay_scatter` →
-  ``merge_positions``): scatter base, then gather-⊕-scatter delta onto
-  the shared slots, then one compaction.  O(capb + capd) work and
-  memory — the base is never re-sorted and nothing is densified.
+* :func:`_append_prog` — one canonical triple batch into the device
+  delta.  The delta is first carried onto the batch's grown keyspaces
+  (:func:`_rerank`); the batch finds its place in the delta and in the
+  base by lexicographic search on (row, col) pairs
+  (:func:`~repro.kernels.sorted_merge.ops.pair_search`), and the merge is
+  :func:`_overlay`.  Each delta entry keeps its lower bound in the base.
+* :func:`_merge_read_prog` — the whole-table ``base ⊕ delta`` that a
+  compaction (and a read that needs the whole table) runs.  The delta
+  brings its lower bounds, so the merge searches nothing: base entries
+  move by log-step shifts (``pack`` over ⊕-cancelled entries, ``spread``
+  over inserted ones), the delta's entries land in the gaps, and the
+  work is ``capb · log2(capd)`` element moves plus scatters of the
+  delta's size — valid for keyspaces of any size, since no (row, col)
+  pair is linearized into one integer.
 * :func:`_dist_merge_prog` — sharded overlay merge: delta triples are
   routed to their owning row shard on host (key-partitioned at insert),
   so the merge is one shard-local concat + canonicalize under
@@ -21,10 +24,12 @@ never densifying (declared via ``@contract``, proven by ``d4mcheck``):
   gathers rerank the resident base onto the union keyspaces in the same
   program.
 
-All programs are cached builders (``functools.lru_cache``) keyed on the
-aggregate name only; array shapes key jit's own trace cache, and ingest
-pads delta buffers to power-of-two capacities so sustained streaming
-reuses a handful of traces instead of recompiling per batch.
+Programs are cached builders (``functools.lru_cache``) keyed on the
+aggregate and the rerank form; array shapes key jit's own cache.  The
+device table keeps every shape in a bounded set — one delta capacity, one
+batch capacity per batch size, a base capacity that only grows by
+doubling — so a stream of inserts, reads and compactions reuses the
+programs its first batches compiled.
 """
 from __future__ import annotations
 
@@ -37,11 +42,10 @@ from jax.sharding import PartitionSpec as P
 from jax import shard_map
 
 from repro.analysis.contracts import contract
-from repro.core.assoc_tensor import coo_compact
 from repro.core.coo import SENT, dedup_sorted_coo
-from repro.kernels.sorted_merge.ops import overlay_scatter
+from repro.kernels.sorted_merge.ops import pack, pair_search, spread
 
-__all__ = ["AGG_OPS", "delta_canon", "merge_read", "dist_merge"]
+__all__ = ["AGG_OPS", "REMAP_SLOTS", "append", "merge_read", "dist_merge"]
 
 # Device/dist ingest aggregates: restricted to the associative AND
 # commutative monoids (jnp.lexsort gives no stability guarantee, so an
@@ -59,68 +63,129 @@ def _agg_op(aggregate: str):
     return op
 
 
+# New keys per axis that a rerank takes as insertion points; past that
+# it takes a full rank map (:func:`_rerank`).
+REMAP_SLOTS = 256
+
+
+def _rerank(x, aux, remap: str):
+    """Ranks onto a grown keyspace.  ``remap="shift"``: ``aux`` holds the
+    old ranks before which new keys were inserted (sorted, sentinel-
+    padded), and a rank moves up by the count at or below it — element-
+    wise compares, no gather.  ``remap="map"``: ``aux`` is the old → new
+    rank map, one gather."""
+    ok = x != SENT
+    if remap == "shift":
+        new = x + jnp.sum(aux[None, :] <= x[:, None], axis=1,
+                          dtype=jnp.int32)
+    else:
+        new = aux[jnp.clip(x, 0, aux.shape[0] - 1)]
+    return jnp.where(ok, new, SENT)
+
+
+def _overlay(op, base, delta, pos, hit, out_cap: int):
+    """Canonical ``base ⊕ delta`` with no sort.
+
+    ``base`` and ``delta`` are ``(rows, cols, vals, extras)`` in canonical
+    order (``extras``: further per-entry arrays that travel with their
+    entries), ``pos``/``hit`` each delta entry's lower bound in the base
+    (:func:`~repro.kernels.sorted_merge.ops.pair_search`).  A delta entry
+    that hits ⊕-combines into its base entry (base value on the left) and
+    unstores it if the result is zero; the others are inserted.  Base
+    entries :func:`pack` over the unstored ones and :func:`spread` over
+    the inserted ones; inserted entries land in the gaps by one scatter
+    of the delta's size.  Returns the merged columns, ``out_cap`` long.
+    """
+    br, bc, bv, bx = base
+    dr, dc, dv, dx = delta
+    grow = out_cap - br.shape[0]
+    fills = [SENT, SENT, jnp.zeros((), bv.dtype)] + [jnp.zeros((), x.dtype)
+                                                     for x in bx]
+    cols = [br, bc, bv] + list(bx)
+    if grow:
+        cols = [jnp.concatenate([a, jnp.full((grow,), f, a.dtype)])
+                for a, f in zip(cols, fills)]
+    n, nd = out_cap, dr.shape[0]
+    ok = dr != SENT
+    dup, add = ok & hit, ok & ~hit
+    at = jnp.minimum(pos, n - 1)
+    merged = op(cols[2][at], dv)
+    gone = dup & (merged == 0)
+    cols[2] = cols[2].at[jnp.where(dup & ~gone, pos, n)].set(merged,
+                                                             mode="drop")
+    gone_at = jnp.zeros(n, jnp.int32).at[jnp.where(gone, pos, n)].set(
+        1, mode="drop")
+    added_at = jnp.zeros(n + 1, jnp.int32).at[
+        jnp.where(add, pos, n + 1)].add(1, mode="drop")
+    shift = jnp.cumsum(added_at)[:n]          # inserted at or before i
+    gone_cum = jnp.cumsum(gone_at)
+    valid = (cols[0] != SENT) & (gone_at == 0)
+    nbits = nd.bit_length()
+    arrays = cols + [shift]                 # each shift travels with its entry
+    fills.append(jnp.int32(0))
+
+    def unstore(args):
+        arrays, valid = args
+        return pack(list(zip(arrays, fills)), gone_cum - gone_at, valid,
+                    nbits)
+
+    arrays, valid = jax.lax.cond(gone.any(), unstore, lambda a: a,
+                                 (arrays, valid))
+    arrays, valid = spread(list(zip(arrays, fills)), arrays[-1], valid,
+                           nbits)
+    # the gaps: lower bound, less the unstored base entries below it, plus
+    # the inserted entries before this one
+    gone_below = jnp.where(pos > 0, gone_cum[jnp.clip(pos - 1, 0, n - 1)], 0)
+    slot = jnp.where(add, pos - gone_below + jnp.cumsum(add) - add, n)
+    out = [a.at[slot].set(d, mode="drop")
+           for a, d in zip(arrays[:-1], [dr, dc, dv] + list(dx))]
+    return out, (out[0] != SENT).sum().astype(jnp.int32)
+
+
 @contract(collectives=0, name="ingest.append",
-          note="delta-buffer canonicalize: one dedup pass, no collectives, "
-               "O(cap) memory")
+          note="one triple batch into the delta: two pair searches, "
+               "log-step moves and batch-sized scatters; no sort, no "
+               "collectives, O(delta capacity) memory")
 @functools.lru_cache(maxsize=16)
-def _delta_canon_prog(aggregate: str):
+def _append_prog(aggregate: str, remap: str):
+    """The delta ⊕ one canonical batch, the delta first carried onto the
+    batch's grown keyspaces.  Batch entries also take their lower bounds
+    in the base (``xqr``/``xqc`` code their keys against the base's
+    keyspaces), which the delta keeps beside each entry until the next
+    whole-table merge."""
     op = _agg_op(aggregate)
 
     @jax.jit
-    def go(rows, cols, vals):
-        return dedup_sorted_coo(rows, cols, vals, op)
+    def go(br, bc, dr, dc, dv, dp, dh, xr, xc, xv, xqr, xqc, rmap, cmap):
+        dr = _rerank(dr, rmap, remap)
+        dc = _rerank(dc, cmap, remap)
+        xp, xh = pair_search(br, bc, xqr, xqc, coded=True)
+        yp, yh = pair_search(dr, dc, xr, xc)
+        (r, c, v, p, h), nnz = _overlay(op, (dr, dc, dv, [dp, dh]),
+                                        (xr, xc, xv, [xp, xh]), yp, yh,
+                                        dr.shape[0])
+        return r, c, v, p, h, nnz
 
     return go
 
 
 @contract(collectives=0, name="ingest.merge_read",
-          note="overlay merge via the sorted_merge rank-count kernel: "
-               "base is never re-sorted, output is O(capb + capd)")
+          note="whole-table base ⊕ delta from the delta's stored lower "
+               "bounds: log-step moves over the base and delta-sized "
+               "scatters; the base is never sorted, output O(capb)")
 @functools.lru_cache(maxsize=16)
-def _merge_read_prog(aggregate: str):
-    """base ⊕ delta overlay; ``ncols`` is a traced scalar so a growing
-    column keyspace never retraces."""
+def _merge_read_prog(aggregate: str, remap: str):
+    """base ⊕ delta into ``out_cap`` slots, the base first carried onto
+    the delta's keyspaces."""
     op = _agg_op(aggregate)
 
-    @jax.jit
-    def go(br, bc, bv, dr, dc, dv, ncols):
-        dr, dc, dv, _ = dedup_sorted_coo(dr, dc, dv, op)
-        cap = br.shape[0] + dr.shape[0]
-        # linearized (row, col) keys: canonical COO order IS linear-key
-        # order, so both sides are sorted & repetition-free as the
-        # rank-count kernel requires (callers guard nr*ncols < 2**31)
-        kb = jnp.where(br != SENT, br * ncols + bc, SENT)
-        kd = jnp.where(dr != SENT, dr * ncols + dc, SENT)
-        i_dst, j_dst, j_dup = overlay_scatter(kb, kd)
-        out_r = jnp.full(cap, SENT, jnp.int32).at[i_dst].set(br, mode="drop")
-        out_c = jnp.full(cap, SENT, jnp.int32).at[i_dst].set(bc, mode="drop")
-        out_v = jnp.zeros(cap, bv.dtype).at[i_dst].set(bv, mode="drop")
-        # delta lands second: a duplicate gathers the base value from the
-        # shared slot and ⊕-combines base-on-the-left (host combine order)
-        cur = out_v.at[j_dst].get(mode="fill", fill_value=0.0)
-        merged = jnp.where(j_dup, op(cur, dv), dv)
-        out_r = out_r.at[j_dst].set(dr, mode="drop")
-        out_c = out_c.at[j_dst].set(dc, mode="drop")
-        out_v = out_v.at[j_dst].set(merged, mode="drop")
-        # zero-drop parity with from_triples: ⊕-cancelled entries unstore
-        keep = (out_r != SENT) & (out_v != 0.0)
-        return coo_compact(out_r, out_c, out_v, keep)
-
-    return go
-
-
-@functools.lru_cache(maxsize=16)
-def _merge_concat_prog(aggregate: str):
-    """Fallback overlay merge (concat + one canonicalize) for keyspaces
-    too large to linearize into int32 — same result, O(cap log cap)."""
-    op = _agg_op(aggregate)
-
-    @jax.jit
-    def go(br, bc, bv, dr, dc, dv):
-        rows = jnp.concatenate([br, dr])
-        cols = jnp.concatenate([bc, dc])
-        vals = jnp.concatenate([bv, dv])
-        return dedup_sorted_coo(rows, cols, vals, op)
+    @partial(jax.jit, static_argnames=("out_cap",))
+    def go(br, bc, bv, dr, dc, dv, dp, dh, rmap, cmap, *, out_cap):
+        br = _rerank(br, rmap, remap)
+        bc = _rerank(bc, cmap, remap)
+        (r, c, v), nnz = _overlay(op, (br, bc, bv, []), (dr, dc, dv, []),
+                                  dp, dh, out_cap)
+        return r, c, v, nnz
 
     return go
 
@@ -160,20 +225,22 @@ def _dist_merge_prog(mesh, aggregate: str, rerank: bool):
 
 # -- eager wrappers (what IngestTable calls) --------------------------------
 
-def delta_canon(rows, cols, vals, aggregate: str):
-    """Canonicalize one padded raw delta buffer → (r, c, v, nnz)."""
-    return _delta_canon_prog(aggregate)(rows, cols, vals)
+def append(base, delta, batch, rmap, cmap, aggregate: str, remap: str):
+    """Fold one canonical batch ``(rows, cols, vals, row_codes,
+    col_codes)`` into ``delta`` ``(rows, cols, vals, pos, hit)``; returns
+    the new delta and its entry count."""
+    prog = _append_prog(aggregate, remap)
+    *out, nnz = prog(base.rows, base.cols, *delta, *batch, rmap, cmap)
+    return tuple(out), nnz
 
 
-def merge_read(base, dr, dc, dv, aggregate: str, *, nrows: int, ncols: int):
-    """Overlay-merge a base AssocTensor's triples with a padded raw delta;
-    returns canonical (r, c, v, nnz) of length ``capb + capd``."""
-    if nrows * max(ncols, 1) < 2**31 - 1:
-        prog = _merge_read_prog(aggregate)
-        return prog(base.rows, base.cols, base.vals, dr, dc, dv,
-                    jnp.int32(max(ncols, 1)))
-    prog = _merge_concat_prog(aggregate)
-    return prog(base.rows, base.cols, base.vals, dr, dc, dv)
+def merge_read(base, delta, rmap, cmap, aggregate: str, remap: str,
+               out_cap: int):
+    """Whole-table ``base ⊕ delta`` → canonical ``(r, c, v, nnz)`` of
+    ``out_cap`` slots."""
+    prog = _merge_read_prog(aggregate, remap)
+    return prog(base.rows, base.cols, base.vals, *delta, rmap, cmap,
+                out_cap=out_cap)
 
 
 def dist_merge(mesh, a_dict, dr, dc, dv, rmap, cmap, aggregate: str,

@@ -27,7 +27,10 @@ planner:
 Ingest batches (``POST /ingest``) flow through the same queue under
 disjoint admission keys — ``("ingest", table)`` vs ``("query", ...)`` —
 so a mutation never batches with reads on the table it mutates; queries
-over ingest tables bind their merge-on-read snapshot at execution time.
+over ingest tables bind at execution time: a plain selection of one
+ingest table reads its base and delta apart
+(:meth:`~repro.ingest.IngestTable.select`), anything else its merge-on-read
+snapshot.
 When the registry holds ingest tables the engine also runs a background
 :class:`~repro.ingest.Compactor`.
 
@@ -37,7 +40,10 @@ dequeue to result.  A response's ``timing`` holds ``queue_s``, ``exec_s``
 and ``total_s`` and, from the spans, ``decode_s`` (wire decode),
 ``selector_s`` (host selector compile and uploads), ``wait_s`` (waiting
 for the device's results), ``to_host_s`` (copying them to the host) and
-``format_s`` (building the JSON payload, copies excluded).
+``format_s`` (building the JSON payload, copies excluded) and, for
+ingest tables, ``ingest_s`` (an insert's own work, lock waits excluded),
+``merge_s`` (combining what a read found in the base and the delta) and
+``table_wait_s`` (waiting for the ingest table's lock).
 
 The execution entry point :func:`serve_execute` carries a ``@contract``:
 shard-local serve queries inherit the zero-collective / never-densify
@@ -89,7 +95,10 @@ _STAGES = (("decode_s", "d4m.decode", False),
            ("selector_s", "d4m.selector", False),
            ("wait_s", "d4m.device_wait", False),
            ("to_host_s", "d4m.to_host", False),
-           ("format_s", "d4m.format", True))
+           ("format_s", "d4m.format", True),
+           ("ingest_s", "d4m.ingest", True),
+           ("merge_s", "d4m.merge", False),
+           ("table_wait_s", "d4m.table_wait", False))
 
 
 def format_result(res, limit: Optional[int] = None) -> Dict[str, Any]:
@@ -377,10 +386,9 @@ class Engine:
                               "ingest_triples": float(out["accepted"])})
             else:
                 if req.expr is None:    # ingest-table query: bind now
-                    with span("d4m.decode"):
-                        req.expr = from_wire(req.payload,
-                                             resolve=self.registry.resolve)
-                res = serve_execute(req.expr)
+                    res = self._execute_ingest_query(req)
+                else:
+                    res = serve_execute(req.expr)
                 limit = req.options.get("limit", self.default_limit)
                 body = format_result(res, limit=limit)
         except (WireError, QueryError) as exc:
@@ -406,6 +414,27 @@ class Engine:
         with self._lat_lock:
             self._latencies.append(t1 - req.t_enqueue)
         req.event.set()
+
+    def _execute_ingest_query(self, req: _Request):
+        """A query over ingest tables, bound at execution time.  A plain
+        selection of one ingest table is the table's own
+        :meth:`~repro.ingest.IngestTable.select` (base and delta apart,
+        results ⊕-combined); anything else runs on the merge-on-read
+        snapshots."""
+        from repro.core.expr import Select
+        from repro.ingest import IngestTable
+
+        from .wire import TableRef
+
+        with span("d4m.decode"):
+            expr = from_wire(req.payload, resolve=None)
+        if isinstance(expr, Select) and isinstance(expr.child, TableRef):
+            table = self.registry.get(expr.child.name)
+            if isinstance(table, IngestTable):
+                return table.select(expr.row_sel, expr.col_sel)
+        with span("d4m.decode"):
+            req.expr = from_wire(req.payload, resolve=self.registry.resolve)
+        return serve_execute(req.expr)
 
     # -- telemetry ----------------------------------------------------------
     def metrics(self) -> MetricsStore:
@@ -457,6 +486,8 @@ class Engine:
         the bench harness calls this between hot/cold mixes)."""
         from repro.core import reset_all_stats
         reset_all_stats()
+        for name in self.registry.ingest_names():
+            self.registry.ingest_table(name).reset_stats()
         self._stores = [MetricsStore("sum") for _ in range(self.workers)]
         with self._lat_lock:
             self._latencies.clear()

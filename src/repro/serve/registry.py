@@ -21,7 +21,8 @@ over ``mesh`` (default: a 1-D ``data`` mesh over every visible device).
 :class:`~repro.ingest.IngestTable` so ``POST /ingest`` can mutate it;
 queries against an ingest table resolve to its merge-on-read
 ``snapshot()`` (stable object identity between mutations, so the plan
-cache still hits).
+cache still hits), except a plain selection of it, which the engine hands
+to the table's own ``select()``.
 """
 from __future__ import annotations
 

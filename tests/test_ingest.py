@@ -93,7 +93,8 @@ def test_device_rejects_order_sensitive_aggregate():
 def test_snapshot_memoized_until_next_mutation():
     base = AssocTensor.from_triples(*_BASE, aggregate="sum")
     t = IngestTable(base, aggregate="sum")
-    assert t.snapshot() is base          # empty delta: stable identity
+    assert t.snapshot() is t.base        # empty delta: stable identity
+    assert t.snapshot() is t.snapshot()
     t.insert(["a"], ["w"], [1.0])
     s1 = t.snapshot()
     assert t.snapshot() is s1            # memo hit between mutations
@@ -103,41 +104,66 @@ def test_snapshot_memoized_until_next_mutation():
     assert t.info()["merge_hit_rate"] > 0
 
 
-def test_merge_kernel_matches_concat_oracle():
-    """The overlay-scatter merge program ≡ the concat+dedup fallback on
-    identical padded operands (the fallback is the semantic oracle)."""
+def _host_dict(rows, cols, vals, op):
+    """⊕ of triples by (row, col) in order, zero results dropped: the
+    plain reference of a canonical merge."""
+    out = {}
+    for r, c, v in zip(rows, cols, vals):
+        k = (int(r), int(c))
+        out[k] = op(out[k], float(v)) if k in out else float(v)
+    return {k: v for k, v in out.items() if v != 0.0}
+
+
+def _canon(rng, cap, n, nkeys, vals):
+    """A canonical sentinel-padded rank triple of ``n`` entries over an
+    ``nkeys × nkeys`` keyspace."""
+    SENT = np.int32(2**31 - 1)
+    pairs = set()
+    while len(pairs) < n:
+        pairs.add((int(rng.integers(nkeys)), int(rng.integers(nkeys))))
+    r, c = (np.array(x, np.int32) for x in zip(*sorted(pairs)))
+    v = rng.choice(vals, n).astype(np.float32)
+    pad = cap - n
+    return (np.concatenate([r, np.full(pad, SENT, np.int32)]),
+            np.concatenate([c, np.full(pad, SENT, np.int32)]),
+            np.concatenate([v, np.zeros(pad, np.float32)]))
+
+
+@pytest.mark.parametrize("agg", ["sum", "min", "max"])
+@pytest.mark.parametrize("nkeys,vals", [
+    (16, [0.5, 1.0, 2.0]),                  # dense collisions
+    (2 ** 16, [1.0, 3.0, 7.0]),             # nrows · ncols = 2^32
+    (12, [-2.0, -1.0, 1.0, 2.0]),           # ⊕ cancels to zero under sum
+], ids=["small", "wide", "cancel"])
+def test_merge_kernel_matches_concat_oracle(agg, nkeys, vals):
+    """The whole-table merge program (pair search, log-step moves, no
+    sort) ≡ the plain ⊕ of base-then-delta triples, on identical padded
+    operands, whatever the keyspace size."""
     import jax.numpy as jnp
-    from repro.ingest.merge import _merge_concat_prog, _merge_read_prog
+    from repro.core.coo import SENT
+    from repro.ingest.merge import REMAP_SLOTS, _merge_read_prog
+    from repro.kernels.sorted_merge.ops import pair_search
 
     rng = np.random.default_rng(3)
-    SENT = np.int32(2**31 - 1)
-
-    def canon(cap, n, ncols):
-        r = np.sort(rng.choice(cap * 4, n, replace=False)).astype(np.int32)
-        c = rng.integers(0, ncols, n).astype(np.int32)
-        v = rng.uniform(0.5, 2.0, n).astype(np.float32)
-        pad = cap - n
-        return (jnp.asarray(np.concatenate([r, np.full(pad, SENT,
-                                                       np.int32)])),
-                jnp.asarray(np.concatenate([c, np.full(pad, SENT,
-                                                       np.int32)])),
-                jnp.asarray(np.concatenate([v, np.zeros(pad, np.float32)])))
-
-    ncols = 16
-    br, bc, bv = canon(64, 40, ncols)
-    dr, dc, dv = canon(32, 20, ncols)
-    for agg in ("sum", "min", "max"):
-        r1, c1, v1, n1 = _merge_read_prog(agg)(br, bc, bv, dr, dc, dv,
-                                               jnp.int32(ncols))
-        r2, c2, v2, n2 = _merge_concat_prog(agg)(br, bc, bv, dr, dc, dv)
-        assert int(n1) == int(n2)
-        k = int(n1)
-        np.testing.assert_array_equal(np.asarray(r1)[:k],
-                                      np.asarray(r2)[:k])
-        np.testing.assert_array_equal(np.asarray(c1)[:k],
-                                      np.asarray(c2)[:k])
-        np.testing.assert_allclose(np.asarray(v1)[:k], np.asarray(v2)[:k],
-                                   rtol=1e-5)
+    br, bc, bv = _canon(rng, 64, min(40, nkeys * nkeys // 2), nkeys, vals)
+    dr, dc, dv = _canon(rng, 32, min(20, nkeys * nkeys // 4), nkeys, vals)
+    dp, dh = pair_search(jnp.asarray(br), jnp.asarray(bc), jnp.asarray(dr),
+                         jnp.asarray(dc))
+    none = jnp.full(REMAP_SLOTS, SENT)
+    r, c, v, n = _merge_read_prog(agg, "shift")(
+        br, bc, bv, dr, dc, dv, dp, dh, none, none, out_cap=128)
+    k = int(n)
+    got = dict(zip(zip(np.asarray(r)[:k].tolist(),
+                       np.asarray(c)[:k].tolist()),
+                   np.asarray(v)[:k].tolist()))
+    assert (np.asarray(r)[k:] == int(SENT)).all()
+    assert list(got) == sorted(got)               # canonical order
+    op = {"sum": lambda a, b: a + b, "min": min, "max": max}[agg]
+    ok = br != int(SENT)
+    dok = dr != int(SENT)
+    want = _host_dict(np.r_[br[ok], dr[dok]], np.r_[bc[ok], dc[dok]],
+                      np.r_[bv[ok], dv[dok]], op)
+    assert got == pytest.approx(want)
 
 
 def test_compaction_preserves_content_and_bumps_version():
@@ -375,6 +401,30 @@ def test_background_compactor_idle_trigger():
         comp.stop()
 
 
+def test_idle_trigger_waits_out_a_steady_writers_gaps(monkeypatch):
+    """A steady open-loop writer is never taken for idle, not even just
+    after a compaction (its gaps are kept across folds); once it stops,
+    three of its longest gaps make it idle."""
+    from repro.ingest import table as table_mod
+
+    clock = [1000.0]
+    monkeypatch.setattr(table_mod.time, "monotonic", lambda: clock[0])
+    t = IngestTable(Assoc(*_BASE), compact_threshold=64)
+    rng = np.random.default_rng(3)
+    for i in range(300):
+        clock[0] += float(rng.exponential(0.1))
+        t.insert([f"r{i}"], ["c"], [1.0])
+        t.maybe_compact(idle_s=0.25)    # the depth trigger, every 64
+        assert t.version == (i + 1) // 64
+        if i < 32:                      # the writer's first gaps
+            continue
+        clock[0] += 0.3                 # past idle_s, short of its gaps
+        assert not t.maybe_compact(idle_s=0.25)
+        clock[0] -= 0.3
+    clock[0] += 3 * max(t._gaps) + 1e-6
+    assert t.maybe_compact(idle_s=0.25) and t.delta_depth == 0
+
+
 def test_background_compactor_counts_failures(monkeypatch, caplog):
     """A failing compaction is counted in info() and logged, and the loop
     keeps serving the other tables; it is never dropped silently."""
@@ -401,6 +451,261 @@ def test_background_compactor_counts_failures(monkeypatch, caplog):
         assert good.version == 1 and good.info()["compact_errors"] == 0
     finally:
         comp.stop()
+
+
+# ---------------------------------------------------------------------------
+# the device table batch by batch against the host reference
+# ---------------------------------------------------------------------------
+
+def _keys(rng, n, hi, prefix):
+    return np.char.add(prefix, rng.integers(0, hi, n).astype(str))
+
+
+def _entries(a):
+    """An Assoc's entries, explicit zeros left out (the device stores
+    none)."""
+    r, c, v = a.triples()
+    return {(x, y): z for x, y, z in zip(r.tolist(), c.tolist(),
+                                         v.tolist()) if z != 0}
+
+
+# case: (key universe of the base, of the batches, batch values, triples
+# per batch, batches, compact before the reads of every k-th batch)
+_STREAMS = {
+    "narrow": (40, 40, [1.0, 2.0, 5.0], 24, 6, 0),
+    "wide": (2 ** 16, 2 ** 16, [1.0, 4.0], 1500, 3, 0),   # 2^32 key pairs
+    "new_keys": (30, 60, [1.0, 3.0], 20, 6, 0),
+    "cancel": (6, 6, [-3.0, -1.0, 1.0, 3.0], 16, 8, 0),
+    "compact": (40, 50, [1.0, 2.0], 24, 8, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_STREAMS))
+def test_device_ingest_matches_host_reference(case):
+    """A device IngestTable after each batch ≡ the host Assoc built from
+    the same triples (``Assoc.combine`` with the same ⊕): selections of
+    single rows (present, absent and new keys), a prefix and the whole
+    table, and the merged snapshot."""
+    from repro.core import Keys, StartsWith
+
+    base_hi, hi, vals, n, batches, every = _STREAMS[case]
+    rng = np.random.default_rng(len(case))
+    br, bc = _keys(rng, 2 * n, base_hi, "r"), _keys(rng, 2 * n, base_hi, "c")
+    bv = rng.choice([1.0, 2.0], 2 * n)
+    t = IngestTable(AssocTensor.from_triples(br, bc, bv, aggregate="sum"),
+                    aggregate="sum", compact_threshold=4 * n)
+    ref = Assoc(br, bc, bv, aggregate="sum")
+    for b in range(batches):
+        r, c = _keys(rng, n, hi, "r"), _keys(rng, n, hi, "c")
+        v = rng.choice(vals, n)
+        t.insert(r, c, v)
+        ref = ref.combine(Assoc(r, c, v, aggregate="sum"), "sum")
+        if every and b % every == every - 1:
+            t.compact()
+        want = _entries(ref)
+        for k in [r[0], r[-1], br[0], "r-absent"]:
+            got = _entries(t.select(Keys([k]), slice(None)))
+            assert got == {e: x for e, x in want.items() if e[0] == k}, \
+                (b, k)
+        got = _entries(t.select(StartsWith("r1"), slice(None)))
+        assert got == {e: x for e, x in want.items()
+                       if e[0].startswith("r1")}
+        assert _entries(t.select(slice(None), slice(None))) == want
+        assert _entries(t.snapshot().to_assoc()) == want
+    # a delta that would overflow its capacity is folded first
+    assert t.info()["compactions"] >= (batches // every if every else 0)
+
+
+def test_read_during_compaction_in_flight(monkeypatch):
+    """A read while a compaction builds the new base: it neither waits
+    for it nor misses a batch, and the swap keeps every entry."""
+    from repro.core import Keys
+
+    t = IngestTable(AssocTensor.from_triples(*_BASE, aggregate="sum"),
+                    aggregate="sum")
+    t.insert(["b", "q"], ["x", "w"], [10.0, 4.0])
+    started, release = threading.Event(), threading.Event()
+    merged = t._merged
+
+    def slow(view, count=True):
+        started.set()
+        assert release.wait(30)
+        return merged(view, count)
+
+    monkeypatch.setattr(t, "_merged", slow)
+    worker = threading.Thread(target=t.compact)
+    worker.start()
+    try:
+        assert started.wait(30)
+        t0 = time.monotonic()
+        got = _entries(t.select(Keys(["b", "q"]), slice(None)))
+        assert time.monotonic() - t0 < 10     # did not wait for the merge
+        assert got == {("b", "x"): 12.0, ("q", "w"): 4.0}
+        assert t.version == 0
+    finally:
+        release.set()
+        worker.join(30)
+    assert t.version == 1 and t.delta_depth == 0
+    assert _entries(t.select(Keys(["b", "q"]), slice(None))) == got
+
+
+def test_reads_writes_and_compactions_under_thread_churn():
+    """One writer, four readers and a background compactor on one device
+    table, the interpreter switching threads every 10 µs: each read sees
+    the state after some whole number of batches (batch b stores (k, cb)
+    and adds 1 to (k, c0)), never a torn view, and never an older state
+    than the same reader saw before; the last state is the reference's."""
+    from repro.core import Keys
+
+    reg = TableRegistry()
+    t = reg.register("mut", IngestTable(
+        AssocTensor.from_triples(["k"], ["c0"], [1.0], aggregate="sum"),
+        aggregate="sum", compact_threshold=8))
+    n_batches, errs, seen = 30, [], []
+    done = threading.Event()
+
+    def writer():
+        try:
+            for b in range(1, n_batches + 1):
+                t.insert(["k", "k"], ["c0", f"c{b:02d}"], [1.0, 1.0])
+        except Exception as exc:
+            errs.append(exc)
+        finally:
+            done.set()
+
+    def reader():
+        last = 0
+        try:
+            while not done.is_set():
+                got = _entries(t.select(Keys(["k"]), slice(None)))
+                b = len(got) - 1
+                assert got == {("k", "c0"): 1.0 + b,
+                               **{("k", f"c{i:02d}"): 1.0
+                                  for i in range(1, b + 1)}}, got
+                assert b >= last
+                last = b
+            seen.append(last)
+        except Exception as exc:
+            errs.append(exc)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    comp = Compactor(reg, interval_s=0.001, idle_s=0.001).start()
+    threads = [threading.Thread(target=writer)] + [
+        threading.Thread(target=reader) for _ in range(4)]
+    try:
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=240)
+    finally:
+        comp.stop()
+        sys.setswitchinterval(old)
+    assert not any(th.is_alive() for th in threads)
+    assert not errs, errs
+    assert t.info()["compactions"] >= 1 and len(seen) == 4
+    assert _entries(t.select(Keys(["k"]), slice(None)))[("k", "c0")] == \
+        1.0 + n_batches
+
+
+_COMPILES = {"n": 0}
+
+
+def _count_backend_compiles():
+    from jax import monitoring
+
+    if _COMPILES.get("on"):
+        return
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            _COMPILES["n"] += 1
+
+    monitoring.register_event_duration_secs_listener(listen)
+    _COMPILES["on"] = True
+
+
+def test_stream_compiles_a_bounded_set_of_programs():
+    """After a warm-up of inserts, reads and one compaction, 50 more
+    batches (new keys among them), their reads and 3 compactions compile
+    nothing: the delta, batch and base capacities stay put and keyspace
+    growth moves ranks without new shapes."""
+    from repro.core import Keys
+
+    _count_backend_compiles()
+    rng = np.random.default_rng(11)
+    br, bc = _keys(rng, 2000, 400, "r"), _keys(rng, 2000, 400, "c")
+    t = IngestTable(AssocTensor.from_triples(br, bc, np.ones(2000),
+                                             aggregate="sum"),
+                    aggregate="sum", compact_threshold=4096)
+
+    def step():
+        r, c = _keys(rng, 64, 420, "r"), _keys(rng, 64, 420, "c")
+        t.insert(r, c, rng.integers(1, 9, 64).astype(float))
+        t.select(Keys([str(r[0])]), slice(None)).nnz()
+
+    for _ in range(4):
+        step()
+    t.compact()
+    step()
+    before = _COMPILES["n"]
+    for i in range(50):
+        step()
+        if i % 16 == 15:
+            t.compact()
+    assert t.info()["compactions"] == 4
+    assert _COMPILES["n"] == before
+
+
+@pytest.mark.parametrize("kind", ["str", "float"])
+def test_batch_ranks_and_base_codes_match_a_plain_search(kind):
+    """An insert ranks each axis of a batch with one search of the
+    delta's keyspace: the grown keyspace, the new keys, the ranks and the
+    codes against the base equal ``np.insert`` and a search of each
+    keyspace, batch after batch, as keys the base lacks pile up."""
+    from repro.ingest.table import _ranked
+
+    def plain(space, base, keys):
+        keys = keys.astype(str) if space.is_string else keys
+        new = np.unique(keys[~np.isin(keys, space.keys)])
+        grown = KeySpace(np.insert(space.keys, np.searchsorted(
+            space.keys, new), new)) if len(new) else space
+        ranks = np.searchsorted(grown.keys, keys)
+        pos = np.searchsorted(base.keys, keys)
+        hit = np.isin(keys, base.keys)
+        return grown, new, ranks, 2 * pos + hit
+
+    rng = np.random.default_rng(5)
+    conv = (lambda a: a.astype(str)) if kind == "str" else \
+        (lambda a: a.astype(np.float64))
+    for _ in range(20):
+        universe = int(rng.integers(8, 300))
+        base = KeySpace(conv(rng.integers(0, universe, universe)))
+        space, lack = base, []
+        for _ in range(4):
+            keys = conv(rng.integers(0, universe + 16,
+                                     int(rng.integers(1, 64))))
+            got = _ranked(space, tuple(lack), keys)
+            want = plain(space, base, keys)
+            assert got[0] == want[0]
+            assert np.array_equal(got[0].keys, want[0].keys)
+            for g, w in zip(got[1:], want[1:]):
+                assert np.array_equal(g, w)
+            lack += [got[1]] if len(got[1]) else []
+            space = got[0]
+
+
+def test_keyspace_digest_hashes_the_keys_bytes():
+    """The digest reads the key array's own buffer: the same value as
+    hashing a copy of its bytes, for a strided array too."""
+    import hashlib
+
+    keys = np.array(["a", "bb", "ccc", "dddd"])
+    for arr in (keys, keys[::2], np.arange(6.0)[::3]):
+        space = KeySpace.from_sorted_unique(arr)
+        want = hashlib.sha1(f"{arr.dtype.str}:{len(arr)}:".encode()
+                            + arr.tobytes()).hexdigest()
+        assert space.digest == want
 
 
 # ---------------------------------------------------------------------------

@@ -396,3 +396,57 @@ def test_multithreaded_cache_hammer(registry):
             >= n_threads * n_iter)
     # the hot pipeline planned once (or a few cold races), then hit
     assert PLAN_STATS["plan_hits"] > 0
+
+
+def test_http_ingest_then_read_on_one_connection():
+    """One keep-alive connection: an insert, then a one-row read of the
+    ingest table that sees it (read-your-writes), the read's ingest
+    stages in its ``timing``, and the write path's counters on
+    ``/stats`` under ``"ingest"``."""
+    from http.client import HTTPConnection
+    from urllib.parse import urlparse
+
+    from repro.core import AssocTensor
+    from repro.ingest import IngestTable
+    from repro.serve import ingest_to_wire
+
+    reg = TableRegistry()
+    reg.register("mut", IngestTable(
+        AssocTensor.from_triples(["a", "b"], ["x", "y"], [1.0, 2.0],
+                                 aggregate="sum"),
+        aggregate="sum", compact_threshold=1000))
+    srv = start_server(reg, workers=2)
+    u = urlparse(srv.url)
+    conn = HTTPConnection(u.hostname, u.port, timeout=120)
+
+    def post(path, body):
+        conn.request("POST", path, body=json.dumps(body),
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        out = json.loads(resp.read())
+        assert resp.status == 200, out
+        return out
+
+    try:
+        w = post("/ingest", ingest_to_wire("mut", ["a", "new"], ["x", "z"],
+                                           [5.0, 7.0]))
+        assert w["result"]["accepted"] == 2
+        assert w["timing"]["ingest_s"] > 0
+        r = post("/query", {"expr": to_wire(
+            TableRef("mut")[Keys(["a", "new"]), :])})
+        got = dict(zip(zip(r["result"]["rows"], r["result"]["cols"]),
+                       r["result"]["vals"]))
+        assert got == {("a", "x"): 6.0, ("new", "z"): 7.0}
+        assert r["timing"]["merge_s"] > 0
+        assert r["timing"]["table_wait_s"] >= 0
+        conn.request("GET", "/stats")
+        st = json.loads(conn.getresponse().read())["ingest"]["mut"]
+        assert st["insert_triples"] == 2 and st["reads"] == 1
+        assert {"merge_entries_in", "merge_entries_out"} <= set(st)
+        reg.ingest_table("mut").compact()   # unless the idle trigger did
+        conn.request("GET", "/stats")
+        st = json.loads(conn.getresponse().read())["ingest"]["mut"]
+        assert (st["merge_entries_in"], st["merge_entries_out"]) == (4, 3)
+    finally:
+        conn.close()
+        srv.close()

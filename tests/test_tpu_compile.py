@@ -18,7 +18,7 @@ from repro.kernels.bsr_spgemm.ops import (bsr_pairlist, bsr_pairlist_reduce,
                                           bsr_spgemm_reduce)
 from repro.kernels.range_extract.ops import range_mask
 from repro.kernels.semiring_matmul.ops import semiring_matmul
-from repro.kernels.sorted_merge.ops import overlay_scatter, rank_count
+from repro.kernels.sorted_merge.ops import rank_count
 
 F32, I32 = jnp.float32, jnp.int32
 N_TILES, N_PAIRS = 256, 4096          # packed 128×128 tiles / tile pairs
@@ -107,12 +107,28 @@ def test_range_mask_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("fn", [rank_count, overlay_scatter],
-                         ids=["rank_count", "overlay_scatter"])
+@pytest.mark.parametrize("fn", [rank_count], ids=["rank_count"])
 def test_sorted_merge_compiles(one_chip, fn):
     text = _compiled_text(one_chip, fn,
                           [((N_BASE,), I32), ((N_DELTA,), I32)])
     assert "tpu_custom_call" in text
+
+
+def test_ingest_merge_compiles_without_a_table_sort(one_chip):
+    # the whole-table base ⊕ delta at the ingest cell's capacities (a
+    # 2^22-slot base, a 2^18-slot delta): no sort of the table's size —
+    # the only sorts are XLA's own over the delta-sized scatter indices
+    from repro.ingest.merge import REMAP_SLOTS, _merge_read_prog
+    base, delta = 2 ** 22, 2 ** 18
+    args = [jax.ShapeDtypeStruct((n,), d, sharding=one_chip) for n, d in
+            [(base, I32), (base, I32), (base, F32), (delta, I32),
+             (delta, I32), (delta, F32), (delta, I32), (delta, jnp.bool_),
+             (REMAP_SLOTS, I32), (REMAP_SLOTS, I32)]]
+    text = _merge_read_prog("sum", "shift").lower(
+        *args, out_cap=base).compile().as_text()
+    sorts = [ln for ln in text.splitlines() if " sort(" in ln]
+    assert not any(f"[{base}]" in ln for ln in sorts), sorts
+    assert all(f"[{delta}]" in ln for ln in sorts), sorts
 
 
 def test_sized_compaction_compiles_without_sort(one_chip):
